@@ -42,9 +42,16 @@ from ..utils.logging import amgx_output
 from ..utils.profiler import cpu_profiler
 
 
+def _refine_scope():
+    """The ``amgx/krylov/refine`` named scope: a solve's true residuals
+    and the f64 refinement's corrections, outside the Krylov stages."""
+    return telemetry.scopes.scope("krylov", "refine")
+
+
 # --------------------------------------------------------------------------
 # Convergence criteria (core/src/convergence/)
 # --------------------------------------------------------------------------
+
 def check_convergence(criterion: str, nrm, nrm_ini, nrm_max, tolerance,
                       alt_rel_tolerance):
     """Return a boolean scalar: has the solve converged?
@@ -1861,18 +1868,24 @@ class Solver:
             return w if lo is None else w + lo.astype(f64)
 
         def refined_fn(b_hi, b_lo, x_hi, x_lo, tol, it_limit):
-            # widen the operator ONCE, outside the while body (see
-            # _spmv_wide: XLA won't hoist the ~2×vals materialisation)
-            Ad64 = self._wide_pack(wide)
-            b64 = widen(b_hi, b_lo)
-            x64 = jnp.zeros_like(b64) if x_hi is None else widen(x_hi, x_lo)
-            r64 = b64 - self._spmv_wide(x64, Ad64, wide)
-            nrm_ini = norm64(r64)
-            m = nrm_ini.shape[0]
-            hist = jnp.zeros((max_iters + 1, m), hist_dt)
-            hist = hist.at[0].set(nrm_ini.astype(hist_dt))
-            done0 = check_convergence(crit, nrm_ini, nrm_ini, nrm_ini,
-                                      tol, alt_tol)
+            # the wide residuals, their norms and the corrections run
+            # under the krylov/refine scope; the inner solve carries its
+            # own stages
+            with _refine_scope():
+                # widen the operator ONCE, outside the while body (see
+                # _spmv_wide: XLA won't hoist the ~2×vals
+                # materialisation)
+                Ad64 = self._wide_pack(wide)
+                b64 = widen(b_hi, b_lo)
+                x64 = jnp.zeros_like(b64) if x_hi is None \
+                    else widen(x_hi, x_lo)
+                r64 = b64 - self._spmv_wide(x64, Ad64, wide)
+                nrm_ini = norm64(r64)
+                m = nrm_ini.shape[0]
+                hist = jnp.zeros((max_iters + 1, m), hist_dt)
+                hist = hist.at[0].set(nrm_ini.astype(hist_dt))
+                done0 = check_convergence(crit, nrm_ini, nrm_ini, nrm_ini,
+                                          tol, alt_tol)
 
             def cond(c):
                 _x, _r, it_tot, _n, done, _h, k, _f = c
@@ -1880,38 +1893,40 @@ class Solver:
 
             def outer(c):
                 x64, r64, it_tot, _nrm, _done, hist, k, fail = c
-                scale = jnp.maximum(jnp.max(jnp.abs(r64)),
-                                    jnp.asarray(tiny, f64))
-                rb = (r64 / scale).astype(dtype)
+                with _refine_scope():
+                    scale = jnp.maximum(jnp.max(jnp.abs(r64)),
+                                        jnp.asarray(tiny, f64))
+                    rb = (r64 / scale).astype(dtype)
                 dx, it, _, _, h_in, f_in = body(
                     rb, jnp.zeros_like(rb),
                     jnp.asarray(inner_tol, dtype), it_limit - it_tot)
-                # the FIRST inner breakdown wins; its first-bad
-                # iteration re-bases onto the global iteration count
-                new = (fail[0] == 0) & (f_in[0] != 0)
-                fail = jnp.where(
-                    new, jnp.stack([f_in[0], it_tot + f_in[1]]), fail)
-                x64n = x64 + scale * dx.astype(f64)
-                r64n = b64 - self._spmv_wide(x64n, Ad64, wide)
-                nrm_n = norm64(r64n)
-                if keep_history:
-                    # place h_in rows 1..it (scaled) at hist rows
-                    # it_tot+1 .. it_tot+it
-                    rows = jnp.arange(max_iters + 1)[:, None]
-                    src = rows - it_tot
-                    take = jnp.broadcast_to(
-                        jnp.clip(src, 0, max_iters), (max_iters + 1, m))
-                    cand = jnp.take_along_axis(h_in, take, axis=0) \
-                        .astype(hist_dt)
-                    mask = (src >= 1) & (src <= it)
-                    hist = jnp.where(mask, cand * scale.astype(hist_dt),
-                                     hist)
-                done_n = check_convergence(crit, nrm_n, nrm_ini, nrm_ini,
-                                           tol, alt_tol) \
-                    | ~jnp.all(jnp.isfinite(nrm_n)) \
-                    | (fail[0] != 0)
-                return (x64n, r64n, it_tot + it, nrm_n, done_n, hist,
-                        k + jnp.asarray(1, jnp.int32), fail)
+                with _refine_scope():
+                    # the FIRST inner breakdown wins; its first-bad
+                    # iteration re-bases onto the global iteration count
+                    new = (fail[0] == 0) & (f_in[0] != 0)
+                    fail = jnp.where(
+                        new, jnp.stack([f_in[0], it_tot + f_in[1]]), fail)
+                    x64n = x64 + scale * dx.astype(f64)
+                    r64n = b64 - self._spmv_wide(x64n, Ad64, wide)
+                    nrm_n = norm64(r64n)
+                    if keep_history:
+                        # place h_in rows 1..it (scaled) at hist rows
+                        # it_tot+1 .. it_tot+it
+                        rows = jnp.arange(max_iters + 1)[:, None]
+                        src = rows - it_tot
+                        take = jnp.broadcast_to(
+                            jnp.clip(src, 0, max_iters), (max_iters + 1, m))
+                        cand = jnp.take_along_axis(h_in, take, axis=0) \
+                            .astype(hist_dt)
+                        mask = (src >= 1) & (src <= it)
+                        hist = jnp.where(mask, cand * scale.astype(hist_dt),
+                                         hist)
+                    done_n = check_convergence(crit, nrm_n, nrm_ini, nrm_ini,
+                                               tol, alt_tol) \
+                        | ~jnp.all(jnp.isfinite(nrm_n)) \
+                        | (fail[0] != 0)
+                    return (x64n, r64n, it_tot + it, nrm_n, done_n, hist,
+                            k + jnp.asarray(1, jnp.int32), fail)
 
             fail0 = jnp.stack([jnp.asarray(0, jnp.int32),
                                jnp.asarray(-1, jnp.int32)])
@@ -1973,13 +1988,14 @@ class Solver:
         ledger = self._collective_ledger
 
         def solve_fn(b, x0, tol, it_limit):
-            r0 = b - spmv(self.Ad, x0)
-            nrm_ini = blas.norm(r0, self.norm_type, self.Ad.block_dim,
-                                self.use_scalar_norm)
-            nrm_ini = jnp.atleast_1d(nrm_ini)
-            history = jnp.zeros((max_iters + 1,) + nrm_ini.shape,
-                                dtype=nrm_ini.dtype)
-            history = history.at[0].set(nrm_ini)
+            with _refine_scope():
+                r0 = b - spmv(self.Ad, x0)
+                nrm_ini = blas.norm(r0, self.norm_type, self.Ad.block_dim,
+                                    self.use_scalar_norm)
+                nrm_ini = jnp.atleast_1d(nrm_ini)
+                history = jnp.zeros((max_iters + 1,) + nrm_ini.shape,
+                                    dtype=nrm_ini.dtype)
+                history = history.at[0].set(nrm_ini)
             state0 = self.solve_init(b, x0)
 
             def cond(carry):
@@ -2052,7 +2068,8 @@ class Solver:
                 # the declared norm is a freshly computed TRUE residual —
                 # in-loop estimates (quasi-residual, CG recursion) only
                 # steer the loop (reference solver.cu:776-805)
-                nrm = jnp.atleast_1d(self.compute_residual_norm(b, x))
+                with _refine_scope():
+                    nrm = jnp.atleast_1d(self.compute_residual_norm(b, x))
             fail = jnp.stack([brk, bad_it])
             return x, it, nrm, nrm_ini, history, fail
 

@@ -507,18 +507,21 @@ class _GMRESBase(Solver):
     def solve_init(self, b, x):
         m, n = self.restart, b.shape[0]
         dt = b.dtype
-        r = b - spmv(self.Ad, x)
-        beta = blas.nrm2(r)
-        V = jnp.zeros((m + 1, n), dt)
-        V = V.at[0].set(jnp.where(beta > 0, r / jnp.where(beta == 0, 1, beta),
-                                  0.0))
-        Z = jnp.zeros((m, n), dt) if self.flexible else jnp.zeros((1, 1), dt)
-        g = jnp.zeros((m + 1,), dt).at[0].set(beta)
-        return _GMRESState(
-            V=V, Z=Z, R=jnp.zeros((m + 1, m), dt), g=g,
-            cs=jnp.zeros((m,), dt), sn=jnp.zeros((m,), dt),
-            x_base=x, quasi_res=jnp.abs(beta),
-            j=jnp.asarray(-1, jnp.int32))
+        # the first Arnoldi vector and the basis storage
+        with _tscopes.scope("krylov", "arnoldi"):
+            r = b - spmv(self.Ad, x)
+            beta = blas.nrm2(r)
+            V = jnp.zeros((m + 1, n), dt)
+            V = V.at[0].set(jnp.where(
+                beta > 0, r / jnp.where(beta == 0, 1, beta), 0.0))
+            Z = jnp.zeros((m, n), dt) if self.flexible \
+                else jnp.zeros((1, 1), dt)
+            g = jnp.zeros((m + 1,), dt).at[0].set(beta)
+            return _GMRESState(
+                V=V, Z=Z, R=jnp.zeros((m + 1, m), dt), g=g,
+                cs=jnp.zeros((m,), dt), sn=jnp.zeros((m,), dt),
+                x_base=x, quasi_res=jnp.abs(beta),
+                j=jnp.asarray(-1, jnp.int32))
 
     def _solve_ls_and_update(self, state, j):
         """x = x_base + basis · y where R[:j+1,:j+1] y = g[:j+1].
@@ -566,15 +569,17 @@ class _GMRESBase(Solver):
         def keep_v0(_):
             return state.V[0], state.g[0]
 
-        v0, beta = jax.lax.cond(restart, fresh_v0, keep_v0, None)
-        V = state.V.at[0].set(v0)
-        x_base = jnp.where(restart, x, state.x_base)
-        zeros_m = jnp.zeros((m,), V.dtype)
-        g = jnp.where(restart, jnp.zeros((m + 1,), V.dtype).at[0].set(beta),
-                      state.g)
-        cs = jnp.where(restart, zeros_m, state.cs)
-        sn = jnp.where(restart, zeros_m, state.sn)
-        state = state._replace(V=V, g=g, cs=cs, sn=sn, x_base=x_base)
+        with _tscopes.scope("krylov", "arnoldi"):
+            v0, beta = jax.lax.cond(restart, fresh_v0, keep_v0, None)
+            V = state.V.at[0].set(v0)
+            x_base = jnp.where(restart, x, state.x_base)
+            zeros_m = jnp.zeros((m,), V.dtype)
+            g = jnp.where(restart,
+                          jnp.zeros((m + 1,), V.dtype).at[0].set(beta),
+                          state.g)
+            cs = jnp.where(restart, zeros_m, state.cs)
+            sn = jnp.where(restart, zeros_m, state.sn)
+            state = state._replace(V=V, g=g, cs=cs, sn=sn, x_base=x_base)
 
         # --- Arnoldi step with CGS2 orthogonalisation; rows > j may hold
         # stale directions from the previous cycle — mask their
@@ -606,7 +611,7 @@ class _GMRESBase(Solver):
                 jnp.where(h_next > 0,
                           w / jnp.where(h_next == 0, 1, h_next), 0.0))
             hcol = hcol.at[j + 1].set(h_next)
-        Z = state.Z.at[j].set(z_j) if self.flexible else state.Z
+            Z = state.Z.at[j].set(z_j) if self.flexible else state.Z
 
         # --- apply previous Givens rotations to the new column
         # (sequential).  The unitary form G = [[c̄, s̄], [−s, c]] with
@@ -647,13 +652,15 @@ class _GMRESBase(Solver):
         def finish(st):
             return self._solve_ls_and_update(st, j)
 
-        x = jax.lax.cond(j == m - 1, finish, lambda st: st.x_base, new_state)
-        # after a boundary update, x_base:=x and clear g so a later
-        # solve_finalize adds nothing on top (y solves R·y = 0)
-        at_boundary = j == m - 1
-        new_state = new_state._replace(
-            x_base=jnp.where(at_boundary, x, new_state.x_base),
-            g=jnp.where(at_boundary, jnp.zeros_like(g), g))
+        with _tscopes.scope("krylov", "update"):
+            x = jax.lax.cond(j == m - 1, finish, lambda st: st.x_base,
+                             new_state)
+            # after a boundary update, x_base:=x and clear g so a later
+            # solve_finalize adds nothing on top (y solves R·y = 0)
+            at_boundary = j == m - 1
+            new_state = new_state._replace(
+                x_base=jnp.where(at_boundary, x, new_state.x_base),
+                g=jnp.where(at_boundary, jnp.zeros_like(g), g))
         return x, new_state
 
     def residual_norm_estimate(self, b, x, state):

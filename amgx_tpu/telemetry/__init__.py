@@ -5,9 +5,10 @@ profiler tree (``amgx_timer.h``), per-level ``levelProfile`` timers and
 the grid-stats table:
 
 * **spans/events** (:mod:`.recorder`): ``span(name, **attrs)`` phase
-  markers (which also aggregate into the legacy profiler tree and the
-  ``jax.profiler.TraceAnnotation`` forwarding) and ``event(name,
-  **attrs)`` point records, appended to a bounded ring buffer;
+  markers (which also aggregate into the legacy profiler tree and
+  enter a ``jax.profiler.TraceAnnotation`` named ``amgx/<marker>``)
+  and ``event(name, **attrs)`` point records, appended to a bounded
+  ring buffer;
 * **metrics** (:mod:`.metrics`): counters/gauges/histograms under
   stable, versioned names (``METRICS``) — SpMV pack-selection counts,
   jit recompiles, phase durations, hierarchy complexities, per-solve
@@ -28,8 +29,9 @@ the grid-stats table:
   estimates — gated by the ``forensics`` config knob;
 * **device-time attribution** (PR 17): :mod:`.scopes` (the versioned
   ``amgx/<area>/<name>`` ``jax.named_scope`` contract every
-  instrumented kernel carries), :mod:`.proftrace` (shared chrome-trace
-  parsing/discovery plumbing), :mod:`.deviceprof` (the profiler-trace
+  instrumented kernel carries), :mod:`.proftrace` (the capture
+  readers: ``.xplane.pb`` device ops joined to their scopes, and
+  chrome-trace JSON), :mod:`.deviceprof` (the profiler-trace
   correlator: per-level / per-pack / per-stage **measured device
   seconds** + measured SpMV bandwidth vs the modelled roofline,
   emitted as the ``device_anatomy`` event and

@@ -1,60 +1,170 @@
 """Device-time attribution: the profiler-trace → scope correlator.
 
 Host spans measure dispatch under JAX's async execution; this module
-answers where the TPU actually spends a cycle.  It parses a
-``jax.profiler`` chrome trace (shared plumbing:
-:mod:`amgx_tpu.telemetry.proftrace`), joins the XLA device-op slices
-back to the ``jax.named_scope`` taxonomy
-(:mod:`amgx_tpu.telemetry.scopes`), and produces a **device-time cycle
+answers where the TPU actually spends a cycle.  It reads a
+``jax.profiler`` capture (``.xplane.pb``, through
+:func:`amgx_tpu.telemetry.proftrace.read_xspace`): the XLA operations of
+the device planes' ``XLA Ops`` line, each joined to the
+``jax.named_scope`` path of its HLO instruction (the ``tf_op`` stat of
+the plane's event metadata), mapped onto the scope taxonomy
+(:mod:`amgx_tpu.telemetry.scopes`).  Only operations that hold no others
+count (a ``while`` event spans its body's), so no device time counts
+twice and host threads never count.  The result is a **device-time cycle
 anatomy**: per-level pre/post-smooth + restrict/prolong seconds, the
-coarse solve, per-pack SpMV device time with *measured* bandwidth
-(cost-model bytes ÷ measured device seconds) next to the modelled
-roofline, per-smoother and per-Krylov-stage splits, and the
-halo-exchange share.
+coarse solve, the shares of busy time on the coarse levels and in the
+smoothers (by each operation's innermost cycle scope), per-pack SpMV
+device time with *measured* bandwidth (cost-model bytes per apply ×
+executions counted in the trace ÷ measured device seconds) next to the
+roofline, per-smoother and per-Krylov-stage splits, the halo-exchange
+share, the largest unscoped operations by Python source, and the idle
+time between operations labelled by the program's host span
+(``amgx/<marker>``) open at the time.
+
+A loaded chrome-trace dict or an event list is accepted as well (the
+synthetic captures of the tests and the checks).
 
 The anatomy is emitted as a schema-validated ``device_anatomy`` event
 (``measured`` provenance bool, like PR 16's ``dist_overlap``) and as
 ``amgx_device_time_seconds_total{scope}`` counters.  Every entry point
-degrades to a ``measured=False`` stub when the trace carries no scoped
-device ops (CPU runs, profiler plugin absent) — host-side file parsing
-only, no profiler dependency.
+degrades to a ``measured=False`` stub when the capture carries no
+scoped device ops (CPU runs, no capture at the path).
 """
 from __future__ import annotations
 
+import bisect
+import os
 import re
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 from . import proftrace, scopes
 
 _LEVEL_RE = re.compile(r"\Aamgx/cycle/level(\d+)/([a-z0-9_]+)\Z")
+_COARSE_RE = re.compile(r"\Aamgx/cycle/(?:level[1-9]\d*/|coarse_solve)")
+_SMOOTH_RE = re.compile(r"\Aamgx/cycle/level\d+/(?:pre|post)_smooth\Z")
 
 #: anatomy sections keyed by scope-name prefix
 _AREA_PREFIX = {a: f"amgx/{a}/" for a in scopes.AREAS}
+
+#: entries kept in the anatomy's ranked lists
+_TOP = 10
+
+
+class _Slice(NamedTuple):
+    """One device operation that holds no others (times in µs)."""
+    pid: object
+    start: float
+    end: float
+    #: the operation's name (an HLO instruction)
+    name: str
+    #: the raw string its scopes were read from
+    text: str
+    #: canonical contract scopes, outermost first
+    scopes: List[str]
+    #: Python file:line (XSpace captures only)
+    source: str
 
 
 def _round_s(us: float) -> float:
     return round(us * 1e-6, 9)
 
 
+def _xspace_slices(path: str):
+    """(leaf device slices, host spans) of the capture at ``path``; None
+    when there is no readable capture."""
+    try:
+        ops, spans = proftrace.read_xspace(path)
+    except (OSError, ValueError):
+        return None
+    out = [_Slice(d, o.start * 1e6, o.end * 1e6, o.name, o.scope,
+                  scopes.extract_scopes(o.scope), o.source)
+           for d, lst in ops.items() for o in lst if not o.container]
+    return out, [(s.name, s.start * 1e6, s.end * 1e6) for s in spans]
+
+
+def _chrome_slices(trace) -> List[_Slice]:
+    out = []
+    for ev in proftrace.complete_slices(proftrace.trace_events(trace)):
+        ts = float(ev["ts"])
+        name = str(ev.get("name", ""))
+        args = ev.get("args")
+        strs = [v for v in (args or {}).values() if isinstance(v, str)] \
+            if isinstance(args, dict) else []
+        out.append(_Slice(ev.get("pid", 0), ts, ts + float(ev["dur"]),
+                          name, "/".join(strs + [name]),
+                          scopes.scopes_in_event(ev), ""))
+    return out
+
+
+def _innermost_cycle(found: List[str]) -> Optional[str]:
+    for s in reversed(found):
+        if s.startswith(_AREA_PREFIX["cycle"]):
+            return s
+    return None
+
+
+def _pack_executions(slices: List[_Slice]) -> Dict[str, int]:
+    """Executions of each SpMV pack counted in the trace.  A call site
+    is the scope path up to its ``amgx/spmv/<pack>`` segment; it ran as
+    often as the least-run operation under it (each operation of one
+    application runs at least once), and a pack's executions are the
+    sum over its call sites."""
+    pre = _AREA_PREFIX["spmv"]
+    sites: Dict[Tuple[object, str, str], Dict[str, int]] = {}
+    for sl in slices:
+        for s in sl.scopes:
+            if not s.startswith(pre):
+                continue
+            i = sl.text.find(s)
+            site = sl.text[:i + len(s)] if i >= 0 else s
+            counts = sites.setdefault((sl.pid, s, site), {})
+            counts[sl.name] = counts.get(sl.name, 0) + 1
+    out: Dict[str, int] = {}
+    for (_pid, s, _site), counts in sites.items():
+        pack = s[len(pre):]
+        out[pack] = out.get(pack, 0) + min(counts.values())
+    return out
+
+
+def _idle_by_span(slices: List[_Slice], spans) -> Dict[str, float]:
+    """Idle µs between the first and the last operation of the first
+    device, by the innermost program host span open at each gap's
+    middle ("none" outside every span)."""
+    if not slices:
+        return {}
+    dev = min((sl.pid for sl in slices), key=str)
+    busy = proftrace.merge_intervals(
+        [(sl.start, sl.end) for sl in slices if sl.pid == dev])
+    gaps = [(e0, s1) for (_s0, e0), (s1, _e1) in zip(busy, busy[1:])]
+    mids = [0.5 * (e0 + s1) for e0, s1 in gaps]
+    labels = ["none"] * len(gaps)
+    # longest span first, so that an inner span relabels its gaps
+    for name, s, e in sorted(spans, key=lambda sp: sp[1] - sp[2]):
+        lo, hi = bisect.bisect_left(mids, s), bisect.bisect_left(mids, e)
+        labels[lo:hi] = [name[len(proftrace.HOST_SPAN_PREFIX):]] * (hi - lo)
+    out: Dict[str, float] = {}
+    for label, (e0, s1) in zip(labels, gaps):
+        out[label] = out.get(label, 0.0) + (s1 - e0)
+    return out
+
+
 def measure_anatomy(trace: "str | dict | Iterable[dict]",
                     pack_bytes: Optional[Dict[str, int]] = None,
-                    pack_dispatches: Optional[Dict[str, int]] = None,
                     peak_gbs: Optional[float] = None) -> dict:
     """The device-time cycle anatomy of one profiler capture.
 
-    ``trace``: a path (file or profiler logdir), a loaded chrome-trace
-    dict, or an iterable of trace events.  ``pack_bytes`` /
-    ``pack_dispatches`` (optional, from :func:`pack_stats`) map SpMV
-    pack names to modelled bytes-per-apply and traced dispatch counts;
-    when both cover a pack the anatomy adds measured GB/s and the
-    roofline fraction next to its device seconds.
+    ``trace``: a path (a ``.xplane.pb`` file or a profiler log
+    directory, whose newest capture is read), a loaded chrome-trace
+    dict, or an iterable of chrome-trace events.  ``pack_bytes``
+    (optional, from :func:`pack_stats`) maps SpMV pack names to modelled
+    bytes per apply; where it covers a pack that the trace saw execute,
+    the anatomy adds measured GB/s and the roofline fraction next to its
+    device seconds.
 
     ALWAYS returns a dict; ``measured`` is True only when at least one
-    device slice carried a contract scope.  Per-scope seconds are the
-    per-device **union** of that scope's slice intervals (overlapping
-    levels / parallel tids do not double count), summed across devices;
-    ``total_device_s`` is the union of every slice the same way, so
-    attributed + unattributed ≡ total.
+    device operation carried a contract scope.  Per-scope seconds are
+    the per-device **union** of that scope's intervals, summed across
+    devices; ``total_device_s`` is the union of every operation the same
+    way, so attributed + unattributed ≡ total.
     """
     if peak_gbs is None:
         # the chip that holds this process's trace; None on the CPU
@@ -62,25 +172,38 @@ def measure_anatomy(trace: "str | dict | Iterable[dict]",
         from .peaks import device_peak
         p = device_peak()
         peak_gbs = p.hbm_gbs if p is not None else None
-    events = proftrace.trace_events(trace)
+    spans = []
+    if isinstance(trace, (str, os.PathLike)):
+        got = _xspace_slices(os.fspath(trace))
+        slices, spans = got if got is not None else ([], [])
+    else:
+        slices = _chrome_slices(trace)
 
     all_iv: Dict[object, List[tuple]] = {}        # pid -> intervals
     scoped_iv: Dict[object, List[tuple]] = {}     # pid -> intervals
     by_scope: Dict[Tuple[object, str], List[tuple]] = {}
-    n_slices = 0
     n_scoped = 0
-    for ev in proftrace.complete_slices(events):
-        n_slices += 1
-        pid = ev.get("pid", 0)
-        iv = (float(ev["ts"]), float(ev["ts"]) + float(ev["dur"]))
-        all_iv.setdefault(pid, []).append(iv)
-        found = scopes.scopes_in_event(ev)
-        if not found:
+    busy_us = coarse_us = smooth_us = 0.0
+    unscoped: Dict[str, float] = {}
+    for sl in slices:
+        iv = (sl.start, sl.end)
+        dur = sl.end - sl.start
+        busy_us += dur
+        all_iv.setdefault(sl.pid, []).append(iv)
+        if not sl.scopes:
+            # an HLO text reads as its instruction's name
+            key = sl.source or sl.name.split(" = ", 1)[0].lstrip("%")
+            unscoped[key] = unscoped.get(key, 0.0) + dur
             continue
         n_scoped += 1
-        scoped_iv.setdefault(pid, []).append(iv)
-        for s in found:
-            by_scope.setdefault((pid, s), []).append(iv)
+        scoped_iv.setdefault(sl.pid, []).append(iv)
+        for s in sl.scopes:
+            by_scope.setdefault((sl.pid, s), []).append(iv)
+        inner = _innermost_cycle(sl.scopes)
+        if inner and _COARSE_RE.match(inner):
+            coarse_us += dur
+        if inner and _SMOOTH_RE.match(inner):
+            smooth_us += dur
 
     total_us = sum(proftrace.union_len(iv) for iv in all_iv.values())
     attrib_us = sum(proftrace.union_len(iv) for iv in scoped_iv.values())
@@ -109,8 +232,7 @@ def measure_anatomy(trace: "str | dict | Iterable[dict]",
     # ---- per-pack SpMV device time + measured bandwidth -------------
     pb = {scopes.sanitize(k): v for k, v in (pack_bytes or {}).items()
           if v}
-    pd = {scopes.sanitize(k): v
-          for k, v in (pack_dispatches or {}).items() if v}
+    execs = _pack_executions(slices)
     spmv: Dict[str, dict] = {}
     for s, us in scope_us.items():
         if not s.startswith(_AREA_PREFIX["spmv"]):
@@ -118,9 +240,9 @@ def measure_anatomy(trace: "str | dict | Iterable[dict]",
         pack = s[len(_AREA_PREFIX["spmv"]):]
         d: dict = {"device_s": _round_s(us)}
         # op_cost events label the base pack kind ("dia", "dia/block"),
-        # dispatch counters the refined label ("dia/slices",
+        # the scopes the refined label ("dia/slices",
         # "dia/block_kernel") — join on the longest base-kind key that
-        # prefixes the dispatch label at a segment boundary
+        # prefixes the scope's pack at a segment boundary
         byt = pb.get(pack)
         if not byt:
             for k in sorted(pb, key=len, reverse=True):
@@ -128,10 +250,11 @@ def measure_anatomy(trace: "str | dict | Iterable[dict]",
                                            or pack[len(k)] in "/_"):
                     byt = pb[k]
                     break
-        n = pd.get(pack)
+        n = execs.get(pack)
+        if n:
+            d["executions"] = int(n)
         if byt and n and us > 0:
             d["bytes_per_apply"] = int(byt)
-            d["dispatches"] = int(n)
             gbs = (float(byt) * float(n)) / (us * 1e-6) / 1e9
             d["measured_gbs"] = round(gbs, 2)
             if peak_gbs:
@@ -143,6 +266,13 @@ def measure_anatomy(trace: "str | dict | Iterable[dict]",
         return {s[len(pre):]: _round_s(us)
                 for s, us in scope_us.items() if s.startswith(pre)}
 
+    def _ranked(d: Dict[str, float]) -> List[list]:
+        return [[k, _round_s(v)] for k, v in
+                sorted(d.items(), key=lambda kv: -kv[1])[:_TOP]]
+
+    def _share(us: float) -> Optional[float]:
+        return round(us / busy_us, 6) if busy_us > 0 else None
+
     return {
         "measured": n_scoped > 0,
         "scope_version": scopes.SCOPE_VERSION,
@@ -150,29 +280,29 @@ def measure_anatomy(trace: "str | dict | Iterable[dict]",
         "attributed_s": _round_s(attrib_us),
         "unattributed_s": _round_s(max(total_us - attrib_us, 0.0)),
         "n_devices": len(all_iv),
-        "n_slices": n_slices,
+        "n_slices": len(slices),
         "n_scoped_slices": n_scoped,
         "scopes": {s: _round_s(us)
                    for s, us in sorted(scope_us.items())},
         "levels": {k: levels[k] for k in sorted(levels, key=int)},
         "coarse_s": coarse_s,
+        "cycle_shares": {"coarse": _share(coarse_us),
+                         "smooth": _share(smooth_us)},
         "spmv": {k: spmv[k] for k in sorted(spmv)},
         "smoothers": _area("smoother"),
         "krylov": _area("krylov"),
         "dist": _area("dist"),
+        "unscoped_top": _ranked(unscoped),
+        "idle_by_span": _ranked(_idle_by_span(slices, spans)),
         "hbm_peak_gbs": float(peak_gbs) if peak_gbs else None,
     }
 
 
-def pack_stats(records: Iterable[dict]) -> Tuple[Dict[str, int],
-                                                 Dict[str, int]]:
-    """(pack → modelled bytes/apply, pack → dispatch count) from
-    recorder ring records: the ``op_cost`` events' cost descriptors and
-    the ``amgx_spmv_dispatch_total`` counter samples.  The biggest
-    descriptor per pack kind wins (the fine operator dominates the
-    bandwidth story)."""
+def pack_stats(records: Iterable[dict]) -> Dict[str, int]:
+    """pack → modelled bytes per apply, from the ``op_cost`` events of
+    recorder ring records.  The biggest descriptor per pack kind wins
+    (the fine operator dominates the bandwidth story)."""
     pack_bytes: Dict[str, int] = {}
-    pack_disp: Dict[str, int] = {}
     for r in records:
         if r.get("kind") == "event" and r.get("name") == "op_cost":
             a = r.get("attrs") or {}
@@ -180,24 +310,17 @@ def pack_stats(records: Iterable[dict]) -> Tuple[Dict[str, int],
             if pack and isinstance(byt, (int, float)) and byt > 0:
                 pack_bytes[str(pack)] = max(
                     pack_bytes.get(str(pack), 0), int(byt))
-        elif r.get("kind") == "counter" and \
-                r.get("name") == "amgx_spmv_dispatch_total":
-            pack = (r.get("labels") or {}).get("pack")
-            if pack:
-                pack_disp[str(pack)] = pack_disp.get(str(pack), 0) \
-                    + int(r.get("value") or 0)
-    return pack_bytes, pack_disp
+    return pack_bytes
 
 
 def capture_anatomy(trace, records: Optional[Iterable[dict]] = None
                     ) -> dict:
-    """:func:`measure_anatomy` fed with pack bytes/dispatch counts from
-    a recorder ring snapshot (default: the live ring)."""
+    """:func:`measure_anatomy` fed with pack bytes from a recorder ring
+    snapshot (default: the live ring)."""
     if records is None:
         from . import recorder
         records = recorder.records()
-    pb, pd = pack_stats(records)
-    return measure_anatomy(trace, pack_bytes=pb, pack_dispatches=pd)
+    return measure_anatomy(trace, pack_bytes=pack_stats(records))
 
 
 def emit(anatomy: dict):

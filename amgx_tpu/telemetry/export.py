@@ -261,9 +261,15 @@ def validate_record(rec: dict):
                          and not isinstance(sec, bool) and sec >= 0,
                          f"device_anatomy scope {name!r} has "
                          f"non-numeric seconds")
-            for k in ("levels", "spmv"):
+            for k in ("levels", "spmv", "cycle_shares"):
                 need(isinstance(a.get(k), dict),
                      f"device_anatomy event missing {k} dict")
+            for k, v in (a.get("cycle_shares") or {}).items():
+                need(v is None or (isinstance(v, (int, float))
+                                   and not isinstance(v, bool)
+                                   and 0 <= v <= 1),
+                     f"device_anatomy cycle share {k!r} is not a "
+                     f"fraction")
         if rec["name"] == "dist_agglomerate":
             # agglomeration decisions (distributed/agglomerate.py):
             # the doctor's sub-mesh lifecycle input

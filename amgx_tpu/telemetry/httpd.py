@@ -255,9 +255,8 @@ class _Handler(BaseHTTPRequestHandler):
             # parse failure must never take the endpoint down)
             try:
                 from . import deviceprof, overlap
+                out["device_anatomy"] = deviceprof.capture_anatomy(out_dir)
                 trace = overlap.find_trace_file(out_dir)
-                out["device_anatomy"] = deviceprof.capture_anatomy(
-                    trace if trace is not None else {"traceEvents": []})
                 out["overlap"] = overlap.measure(
                     trace if trace is not None else {"traceEvents": []})
             except Exception as e:   # noqa: BLE001 — summary is extra

@@ -27,7 +27,9 @@ METRICS: Dict[str, Tuple[str, str]] = {
         ("counter", "SpMV calls where a packed kernel layout fell back "
                     "to a generic path {pack,reason}"),
     "amgx_jit_trace_total":
-        ("counter", "jax.jit python-cache misses (retraces), process-wide"),
+        ("counter", "jax.jit python-cache misses (retraces), process-wide, "
+                    "by traced function {fun} (the first 32 names; later "
+                    "ones as other)"),
     "amgx_device_time_seconds_total":
         ("counter", "profiler-measured device seconds attributed to a "
                     "named-scope contract scope (telemetry/deviceprof.py) "

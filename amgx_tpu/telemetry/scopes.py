@@ -25,7 +25,8 @@ The vocabulary per area:
 * ``smoother`` — the registered smoother's config name, sanitised
   (solvers/base.py wraps every smoother application)
 * ``krylov`` — the fixed stage vocabulary :data:`KRYLOV_STAGES`
-  (solvers/krylov.py)
+  (solvers/krylov.py; ``refine``, a solve's true residuals and the f64
+  refinement's corrections, in solvers/base.py)
 * ``dist``   — ``halo_exchange`` (distributed/matrix.py)
 
 Bump :data:`SCOPE_VERSION` when names change meaning — the
@@ -37,7 +38,8 @@ import re
 from typing import Iterable, List, Optional
 
 #: version of the naming contract carried by every device_anatomy event
-SCOPE_VERSION = 1
+#: (2: the ``krylov/refine`` stage)
+SCOPE_VERSION = 2
 
 #: the taxonomy's top-level areas
 AREAS = ("cycle", "spmv", "smoother", "krylov", "dist")
@@ -56,7 +58,8 @@ SPMV_PACKS = (
 )
 
 #: the Krylov per-stage vocabulary (solvers/krylov.py)
-KRYLOV_STAGES = ("precond", "reduce", "arnoldi", "givens", "update")
+KRYLOV_STAGES = ("precond", "reduce", "arnoldi", "givens", "update",
+                 "refine")
 
 #: the per-level cycle components (amg/cycles.py)
 CYCLE_COMPONENTS = ("pre_smooth", "post_smooth", "restrict", "prolong")

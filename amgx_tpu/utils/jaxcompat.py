@@ -15,8 +15,10 @@ cache HITS too — the cache-event listener below flags those per thread
 so a warm-cache executable load is counted as
 ``amgx_compile_cache_hits_total``, NOT as an ``amgx_jit_compile_total``
 recompile (which is the operational meaning callers assert on, e.g. the
-cross-process zero-recompile test).  The listeners are process-wide and
-permanent — JAX has no unregister — and cost one dict update when
+cross-process zero-recompile test).  Every trace is also counted, with
+its seconds, by the traced function's name whether telemetry is on or
+not (:func:`trace_stats`).  The listeners are process-wide and
+permanent — JAX has no unregister — and cost a few dict updates when
 telemetry is disabled.
 
 ``configure_compilation_cache`` is the ONE place that decides where
@@ -76,6 +78,64 @@ def compile_cache_stats() -> dict:
         return dict(_cc_stats)
 
 
+#: ungated process totals of jit traces (python-cache misses) and their
+#: seconds, overall and by traced function name
+_trace_totals = {"traces": 0, "trace_s": 0.0}
+_trace_by_fun: dict = {}
+#: function names that keep their own ``fun`` label on
+#: ``amgx_jit_trace_total``; later names count under "other"
+_TRACE_FUN_LABELS = 32
+_trace_fun_labels: set = set()
+
+
+def trace_stats() -> dict:
+    """Process totals of jit traces, counted since
+    :func:`install_compile_counter` whether telemetry is on or not:
+    ``{"traces": n, "trace_s": seconds, "by_fun": {name: {"traces": n,
+    "trace_s": seconds}}}``, ``by_fun`` ordered by traces, most first.
+
+    A jit called while another traces is traced inside it, so seconds
+    are counted once: ``trace_s`` is the wall time in outermost traces,
+    and a function's ``trace_s`` its own time, without the traces nested
+    in it (the functions' seconds sum to the total)."""
+    with _cc_lock:
+        by_fun = sorted(_trace_by_fun.items(),
+                        key=lambda kv: (-kv[1][0], -kv[1][1]))
+        return {"traces": _trace_totals["traces"],
+                "trace_s": _trace_totals["trace_s"],
+                "by_fun": {k: {"traces": n, "trace_s": sec}
+                           for k, (n, sec) in by_fun}}
+
+
+def _trace_started():
+    """A trace began on this thread (JAX reports its start time as a
+    scalar): open a frame that its nested traces charge."""
+    stack = getattr(_cc_tls, "traces", None)
+    if stack is None:
+        stack = _cc_tls.traces = []
+    stack.append(0.0)
+
+
+def _count_trace(fun: str, duration: float) -> str:
+    """Add one finished trace to the process totals; returns the
+    function's ``fun`` label."""
+    stack = getattr(_cc_tls, "traces", None)
+    nested = stack.pop() if stack else 0.0
+    if stack:
+        stack[-1] += duration
+    with _cc_lock:
+        _trace_totals["traces"] += 1
+        if not stack:
+            _trace_totals["trace_s"] += duration
+        c = _trace_by_fun.setdefault(fun, [0, 0.0])
+        c[0] += 1
+        c[1] += max(duration - nested, 0.0)
+        if fun not in _trace_fun_labels \
+                and len(_trace_fun_labels) < _TRACE_FUN_LABELS:
+            _trace_fun_labels.add(fun)
+        return fun if fun in _trace_fun_labels else "other"
+
+
 def thread_cache_hits() -> int:
     """Persistent-cache hits observed on THIS thread (monotonic) —
     compile events fire on the compiling thread, so a delta across a
@@ -87,7 +147,8 @@ def thread_cache_hits() -> int:
 def install_compile_counter() -> bool:
     """Register the jit cache-miss + persistent-cache listeners
     (idempotent); returns True when listeners are in place.  Counts land
-    in ``amgx_jit_trace_total`` / ``amgx_jit_compile_total`` /
+    in :func:`trace_stats` and, with telemetry on, in
+    ``amgx_jit_trace_total{fun}`` / ``amgx_jit_compile_total`` /
     ``amgx_compile_cache_{hits,misses}_total`` and compile durations in
     the ``amgx_jit_compile_seconds`` histogram."""
     global _compile_listener_installed
@@ -126,14 +187,18 @@ def install_compile_counter() -> bool:
     def _on_duration(event, duration, **kwargs):
         try:
             cache_hit = False
+            fun = None
             if event == _COMPILE_EVENT:
                 cache_hit = getattr(_cc_tls, "hit", False)
                 _cc_tls.hit = False
+            elif event == _TRACE_EVENT:
+                fun = _count_trace(str(kwargs.get("fun_name", "?")),
+                                   float(duration))
             from ..telemetry import metrics, recorder
             if not recorder.is_enabled():
                 return
             if event == _TRACE_EVENT:
-                metrics.counter_inc("amgx_jit_trace_total")
+                metrics.counter_inc("amgx_jit_trace_total", fun=fun)
             elif event == _COMPILE_EVENT:
                 if not cache_hit:
                     metrics.counter_inc("amgx_jit_compile_total")
@@ -154,10 +219,15 @@ def install_compile_counter() -> bool:
         except Exception:   # a metrics bug must never break compilation
             pass
 
+    def _on_scalar(event, value, **kwargs):
+        if event == _TRACE_EVENT:
+            _trace_started()
+
     try:
         import jax.monitoring
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
         jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_scalar_listener(_on_scalar)
     except Exception:    # pragma: no cover - depends on the jax version
         return False
     _compile_listener_installed = True

@@ -4,8 +4,11 @@ Reference: ``base/include/amgx_timer.h`` — ``Profiler_tree`` /
 ``Profiler_entry`` aggregating RAII ``AMGX_CPU_PROFILER`` markers
 (``amgx_timer.h:150-274``), per-level phase timers (``levelProfile``), and
 the ``TimerMap``.  Here: nested context-manager markers aggregated in a
-tree, plus optional forwarding to ``jax.profiler.TraceAnnotation`` so
-markers show up in XLA profiles.
+tree.  Every marker also enters a ``jax.profiler.TraceAnnotation`` named
+``amgx/<marker>``, so a profiler capture holds the program's host phases
+on the device trace's clock (``telemetry/deviceprof.py`` labels device
+idle time with them); with no profiler session active that is one TraceMe
+check.
 
 Every marker also doubles as a telemetry span: when the structured
 telemetry layer (:mod:`amgx_tpu.telemetry`) is enabled, ``scope()``
@@ -20,14 +23,12 @@ import time
 import warnings
 from typing import Dict, Optional
 
+import jax.profiler as _jax_profiler
+
 from ..telemetry import recorder as _telemetry
 
-_forward_to_jax = False
-
-
-def enable_jax_trace_annotations(enable: bool = True):
-    global _forward_to_jax
-    _forward_to_jax = enable
+#: prefix of the markers' names in a profiler capture
+SPAN_PREFIX = "amgx/"
 
 
 class ProfilerEntry:
@@ -58,14 +59,11 @@ class ProfilerTree:
         entry = self._stack[-1].child(name)
         self._stack.append(entry)
         try:
-            # annotation setup BEFORE the timer starts: an import/enter
+            # annotation setup BEFORE the timer starts: an enter
             # failure here must neither corrupt the stack depth (the
             # outer finally pops) nor charge its cost to the entry
-            ann = None
-            if _forward_to_jax:
-                import jax
-                ann = jax.profiler.TraceAnnotation(name)
-                ann.__enter__()
+            ann = _jax_profiler.TraceAnnotation(SPAN_PREFIX + name)
+            ann.__enter__()
             try:
                 sid = _telemetry.span_begin(name, _attrs) \
                     if _telemetry.is_enabled() else None
@@ -77,8 +75,7 @@ class ProfilerTree:
                     entry.count += 1
                     _telemetry.span_end(sid, name)
             finally:
-                if ann is not None:
-                    ann.__exit__(None, None, None)
+                ann.__exit__(None, None, None)
         finally:
             self._stack.pop()
 

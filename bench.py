@@ -345,7 +345,7 @@ def _bench_device_anatomy(slv, n, dtype):
     """Profile ONE warm headline solve and attribute its device time to
     the ``amgx/*`` named-scope contract (ISSUE 17):
     telemetry.deviceprof joins the capture's XLA device slices back to
-    the scope taxonomy, with the same solve's op-cost/dispatch records
+    the scope taxonomy, with the same solve's op-cost records
     feeding the measured-bandwidth column.  On CPU the trace carries no
     scoped device ops and the block honestly reports measured=false."""
     import tempfile
@@ -360,9 +360,8 @@ def _bench_device_anatomy(slv, n, dtype):
             with jax.profiler.trace(td):
                 res = slv.solve(b)
                 _sync(res.x)
-        trace = telemetry.proftrace.find_trace_file(td)
-        return telemetry.deviceprof.capture_anatomy(
-            trace or {"traceEvents": []}, records=cap.records)
+        return telemetry.deviceprof.capture_anatomy(td,
+                                                    records=cap.records)
 
 
 def _bench_memory(slv):

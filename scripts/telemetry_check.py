@@ -1074,6 +1074,30 @@ def main():
              "t": 0.0, "tid": 0, "sid": None, "attrs": stub})
     except ValueError as e:
         fail(f"measured=false anatomy stub failed validation: {e}")
+    # (c) the recorded TPU capture (.xplane.pb): its device ops join to
+    # their named-scope paths, the books balance, the event validates
+    xplane = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "tests", "data",
+        "fixture.xplane.pb")
+    rec_a = deviceprof.measure_anatomy(xplane)
+    if not rec_a["measured"] or not rec_a["levels"]:
+        fail("the recorded TPU capture measured no scoped device time")
+    if abs(rec_a["attributed_s"] + rec_a["unattributed_s"]
+           - rec_a["total_device_s"]) > 2e-9:
+        fail(f"recorded anatomy does not balance: {rec_a['attributed_s']}"
+             f" + {rec_a['unattributed_s']} != "
+             f"{rec_a['total_device_s']}")
+    try:
+        telemetry.validate_record(
+            {"kind": "event", "name": "device_anatomy", "seq": 1,
+             "t": 0.0, "tid": 0, "sid": None, "attrs": rec_a})
+    except ValueError as e:
+        fail(f"recorded-capture anatomy failed validation: {e}")
+    # (d) retraces by function, counted with telemetry off too
+    from amgx_tpu.utils.jaxcompat import trace_stats
+    ts = trace_stats()
+    if ts["traces"] <= 0 or not ts["by_fun"]:
+        fail(f"trace_stats counted no traces after a solve: {ts}")
 
     # 19. HBM ledger (ISSUE 18): (a) a real small solve with the
     # `memledger=1` knob emits schema-valid hbm_snapshot events and the

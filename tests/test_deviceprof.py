@@ -2,7 +2,7 @@
 the named-scope contract, the profiler-trace correlator, the anatomy
 arithmetic, and the emit → schema-validation round trip."""
 import gzip
-import json
+from pathlib import Path
 
 import pytest
 
@@ -11,6 +11,11 @@ from amgx_tpu.telemetry import deviceprof, proftrace, scopes
 from tests.conftest import synthetic_trace_events
 
 pytestmark = pytest.mark.deviceprof
+
+#: one FGMRES + aggregation AMG solve of the 32^3 7-point Poisson,
+#: recorded on a TPU v5e (the benchmark's trace-reader fixture)
+XPLANE_FIXTURE = (Path(__file__).resolve().parents[1] / "benchmark"
+                  / "tests" / "data" / "fixture.xplane.pb")
 
 
 # ------------------------------------------------------- scope contract
@@ -115,19 +120,26 @@ def test_measured_bandwidth_joins_cost_and_dispatch(chrome_trace):
     # the fixture is a TPU trace: give the chip's peak explicitly (this
     # process runs on the CPU, which has none)
     from amgx_tpu.telemetry.peaks import PEAKS
+    # the pack's call site runs 4 times in the trace: executions are
+    # counted there, not from traced dispatches
+    site = "amgx/cycle/level1/pre_smooth/amgx/spmv/dia/slices/"
+    events = [dict(ph="X", pid=0, tid=1, ts=1000 + 50 * i + 25 * k,
+                   dur=25, name=f"fusion.2{k}",
+                   args={"name": site + f"fusion.2{k}"})
+              for i in range(4) for k in (0, 1)]
     a = deviceprof.measure_anatomy(
-        chrome_trace,
+        {"traceEvents": chrome_trace["traceEvents"] + events},
         pack_bytes={"dia": 8000},              # op_cost base kind
-        pack_dispatches={"dia/slices": 4},     # refined dispatch label
         peak_gbs=PEAKS["TPU v5 lite"].hbm_gbs)
     e = a["spmv"]["dia/slices"]
-    assert e["device_s"] == pytest.approx(100e-6)
+    assert e["device_s"] == pytest.approx(300e-6)
     assert e["bytes_per_apply"] == 8000
-    assert e["dispatches"] == 4
-    # 8000 B × 4 / 100 µs = 0.32 GB/s
-    assert e["measured_gbs"] == pytest.approx(0.32, rel=1e-3)
+    # one execution of the fixture's site, four of the new one
+    assert e["executions"] == 5
+    # 8000 B × 5 / 300 µs = 0.1333 GB/s
+    assert e["measured_gbs"] == pytest.approx(0.13, abs=0.005)
     assert e["roofline_fraction"] == pytest.approx(
-        0.32 / a["hbm_peak_gbs"], rel=1e-2)
+        8000 * 5 / 300e-6 / 1e9 / a["hbm_peak_gbs"], abs=1e-6)
 
 
 def test_bandwidth_absent_without_stats(chrome_trace):
@@ -161,17 +173,20 @@ def test_malformed_trace_inputs():
         {"traceEvents": "garbage"})["measured"] is False
 
 
-def test_trace_file_discovery(tmp_path, chrome_trace):
+def test_trace_file_discovery(tmp_path):
+    """A profiler log directory resolves to its newest ``.xplane.pb``;
+    a chrome-trace file at the path is no capture."""
     d = tmp_path / "plugins" / "profile" / "run1"
     d.mkdir(parents=True)
-    p = d / "host.trace.json.gz"
-    with gzip.open(p, "wt") as f:
-        json.dump(chrome_trace, f)
-    tf = proftrace.find_trace_file(str(tmp_path))
-    assert tf == str(p)
-    a = deviceprof.measure_anatomy(tf)
+    (d / "host.trace.json.gz").write_bytes(gzip.compress(b"{}"))
+    assert proftrace.find_xplane_file(str(tmp_path)) is None
+    assert deviceprof.measure_anatomy(str(tmp_path))["measured"] is False
+    p = d / "host.xplane.pb"
+    p.write_bytes(XPLANE_FIXTURE.read_bytes())
+    assert proftrace.find_xplane_file(str(tmp_path)) == str(p)
+    a = deviceprof.measure_anatomy(str(tmp_path))
     assert a["measured"] is True
-    assert a["total_device_s"] == pytest.approx(330e-6)
+    assert a["total_device_s"] == pytest.approx(0.004580665, abs=1e-9)
 
 
 # ------------------------------------------------ recorder ring plumbing
@@ -187,9 +202,8 @@ def test_pack_stats_from_ring_records():
          "labels": {"pack": "dia/slices"}, "value": 2},
         {"kind": "counter", "name": "amgx_other", "value": 7},
     ]
-    pb, pd = deviceprof.pack_stats(records)
+    pb = deviceprof.pack_stats(records)
     assert pb == {"dia": 9000}          # biggest descriptor wins
-    assert pd == {"dia/slices": 5}      # samples accumulate
 
 
 def test_emit_round_trip_validates_and_counts(chrome_trace):
@@ -265,8 +279,7 @@ def test_doctor_renders_device_anatomy(tmp_path, chrome_trace):
     try:
         telemetry.clear()
         a = deviceprof.measure_anatomy(
-            chrome_trace, pack_bytes={"dia": 8000},
-            pack_dispatches={"dia/slices": 4})
+            chrome_trace, pack_bytes={"dia": 8000})
         deviceprof.emit(a)
         telemetry.dump_jsonl(str(path))
     finally:
@@ -316,6 +329,5 @@ def test_peak_table_never_assumes_a_device():
                                                 device_kind="TPU v9"))
     assert peaks.device_peak() is None       # this process is on the CPU
     a = deviceprof.measure_anatomy(
-        {"traceEvents": []}, pack_bytes={"dia": 1},
-        pack_dispatches={"dia/slices": 1})
+        {"traceEvents": []}, pack_bytes={"dia": 1})
     assert a["hbm_peak_gbs"] is None

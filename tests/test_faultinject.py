@@ -17,6 +17,7 @@ from amgx_tpu.errors import RC, AMGXError, FailureKind, SolveStatus
 from amgx_tpu.io import poisson5pt, poisson7pt
 from amgx_tpu.solvers import SolverFactory
 from amgx_tpu.utils import faultinject
+from amgx_tpu.utils.jaxcompat import trace_stats
 from amgx_tpu.utils.thread_manager import ThreadManager
 
 pytestmark = pytest.mark.chaos
@@ -237,10 +238,9 @@ def test_knobs_off_bit_identical_and_zero_retraces():
     telemetry.enable(4096)
     try:
         telemetry.reset()
-        reg = telemetry.registry()
-        before = reg.get_counter("amgx_jit_trace_total")
+        before = trace_stats()["traces"]
         res = s.solve(b)
-        after = reg.get_counter("amgx_jit_trace_total")
+        after = trace_stats()["traces"]
     finally:
         telemetry.disable()
         telemetry.reset()

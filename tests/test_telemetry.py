@@ -90,7 +90,6 @@ def test_profiler_scope_annotation_failure_keeps_stack_balanced(
             return False
 
     tree = amgx_profiler.ProfilerTree()
-    monkeypatch.setattr(amgx_profiler, "_forward_to_jax", True)
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Boom)
     with pytest.raises(RuntimeError, match="annotation enter failed"):
         with tree.scope("ann"):
