@@ -139,6 +139,45 @@ def _child_matrix_dia(parent: Matrix, offsets, vals) -> Matrix:
     return m
 
 
+def _first_member_order(agg: np.ndarray, nc: int) -> Optional[np.ndarray]:
+    """Coarse ids in the order of each aggregate's first fine row
+    (``order[new] = old``), or None when ``agg`` is numbered so already."""
+    _, first = np.unique(agg, return_index=True)
+    order = np.argsort(first, kind="stable")
+    if np.array_equal(order, np.arange(nc)):
+        return None
+    return order
+
+
+def _permute_host(host, order: np.ndarray, b: int):
+    """``Π host Πᵀ`` for the block-row permutation ``order`` (new → old):
+    the stored entries move, their values are untouched."""
+    m = host if b == 1 else sp.bsr_matrix(host, blocksize=(b, b))
+    n = len(order)
+    inv = np.empty_like(order)
+    inv[order] = np.arange(n)
+    slot = sp.csr_matrix((np.arange(1, len(m.indices) + 1), m.indices,
+                          m.indptr), shape=(n, n))[order]
+    cls = sp.csr_matrix if b == 1 else sp.bsr_matrix
+    return cls((m.data[slot.data - 1], inv[slot.indices], slot.indptr),
+               shape=m.shape)
+
+
+def _bandwidth(m: Matrix) -> Optional[int]:
+    """max |i − j| over the stored (block) entries of a level operator,
+    from what the matrix already holds (no device download); None when
+    it holds neither host rows nor diagonal offsets."""
+    if m._dia is not None:
+        return max(abs(int(o)) for o in m._dia[0])
+    if m._host is not None:
+        h = m._host
+        rows = np.repeat(np.arange(len(h.indptr) - 1), np.diff(h.indptr))
+        return int(np.abs(rows - h.indices).max()) if h.nnz else 0
+    if getattr(m._device, "fmt", None) == "dia":
+        return max(abs(int(o)) for o in m._device.dia_offsets)
+    return None
+
+
 class AMGHierarchy:
     def __init__(self, cfg: AMGConfig, scope: str):
         self.cfg = cfg
@@ -336,6 +375,7 @@ class AMGHierarchy:
             nxt = self._build_classical_device_pipeline(cur)
             if nxt is not None:
                 cur = nxt
+        first_coarsened = len(self.levels)
         stream = None
         if self.algorithm == "CLASSICAL" and cur.dist is None:
             from ..utils.thread_manager import ThreadManager
@@ -381,7 +421,55 @@ class AMGHierarchy:
 
                 stream.push_work(_stream_upload)
             cur = Ac
-        return cur
+        return self._relabel_aggregation_levels(first_coarsened, cur)
+
+    def _relabel_aggregation_levels(self, first: int,
+                                    coarsest: Matrix) -> Matrix:
+        """Number the coarse unknowns of the single-device aggregation
+        levels from ``first`` on by each aggregate's first fine row;
+        returns the coarsest matrix, renumbered with them.
+
+        Selectors number pairs by their lower node and append leftover
+        singletons, so a coarse operator's couplings land up to ~n
+        columns apart, level after level, past the windowed SpMV
+        kernel's gate.  The renumbering runs after the whole chain is
+        built with the selectors' own numbering, so each selector saw
+        the operator (and tie-breaks) it always sees: the aggregates stay
+        the same partitions and each coarse operator becomes
+        ``Π A Πᵀ`` of the one built.  A level's coarse side is renumbered
+        only when the next level aggregates too (a DIA level pairs by
+        index) or it is the coarsest.  ``_structure`` records the
+        renumbered aggregates, so a re-setup's Galerkin products come
+        out renumbered directly."""
+        n_lv = len(self.levels)
+        fine = None     # the renumbered fine matrix of level i, or None
+        fine_order = None
+        for i in range(first, n_lv):
+            kind, data = self._structure[i]
+            if kind != "aggregation":
+                continue
+            agg, nc = data
+            if fine_order is not None:
+                agg = agg[fine_order]
+            order = None
+            if i + 1 == n_lv or self._structure[i + 1][0] == "aggregation":
+                order = _first_member_order(agg, nc)
+            if order is not None:
+                new_id = np.empty(nc, dtype=agg.dtype)
+                new_id[order] = np.arange(nc, dtype=agg.dtype)
+                agg = new_id[agg]
+            if fine is not None or order is not None:
+                A = fine if fine is not None else self.levels[i].A
+                self.levels[i] = AggregationLevel(A, i, agg, nc)
+                self._structure[i] = ("aggregation", (agg, nc))
+            fine, fine_order = None, order
+            if order is None:
+                continue
+            old = self.levels[i + 1].A if i + 1 < n_lv else coarsest
+            b = old.block_dim
+            fine = _child_matrix(old, _permute_host(old.host, order, b),
+                                 block_dim=b)
+        return fine if fine is not None else coarsest
 
     def _setup_reuse(self, A: Matrix):
         """Keep coarsening structure; refresh numeric values
@@ -1635,9 +1723,14 @@ class AMGHierarchy:
         # deeper levels dangling in the registry snapshot
         telemetry.registry().gauge_clear("amgx_level_rows")
         telemetry.registry().gauge_clear("amgx_level_nnz")
-        for i, (n, nnz) in enumerate(sizes):
+        telemetry.registry().gauge_clear("amgx_level_bandwidth")
+        mats = [lvl.A for lvl in self.levels] + [self.coarsest]
+        for i, ((n, nnz), m) in enumerate(zip(sizes, mats)):
             telemetry.gauge_set("amgx_level_rows", n, level=i)
             telemetry.gauge_set("amgx_level_nnz", nnz, level=i)
+            bw = _bandwidth(m)
+            if bw is not None:
+                telemetry.gauge_set("amgx_level_bandwidth", bw, level=i)
         telemetry.gauge_set("amgx_operator_complexity", op_cmpl)
         telemetry.gauge_set("amgx_grid_complexity", grid_cmpl)
         self._emit_cost_telemetry(sizes)

@@ -46,6 +46,9 @@ METRICS: Dict[str, Tuple[str, str]] = {
         ("gauge", "rows of one hierarchy level {level}"),
     "amgx_level_nnz":
         ("gauge", "stored nonzeros of one hierarchy level {level}"),
+    "amgx_level_bandwidth":
+        ("gauge", "max |i - j| over the stored (block) entries of one "
+                  "hierarchy level's operator {level}"),
     "amgx_operator_complexity":
         ("gauge", "sum(level nnz) / fine nnz of the last hierarchy"),
     "amgx_grid_complexity":

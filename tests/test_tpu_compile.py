@@ -128,6 +128,24 @@ def test_binned_kernel_compiles_coarse_operator(monkeypatch, one_chip):
     compile_for_chip(monkeypatch, one_chip, pallas_csr.binned_spmv, Ad)
 
 
+def test_window_kernel_compiles_aggregation_level(monkeypatch, one_chip):
+    # the benchmark cell's largest coarse operator: SIZE_2 level 1 of a
+    # 64³ Laplacian (162,468 rows, up to 73 entries a row), its coarse
+    # unknowns numbered by first fine row as the hierarchy numbers them
+    from amgx_tpu.amg.aggregation.galerkin import galerkin_coarse
+    from amgx_tpu.amg.aggregation.selectors import create_selector
+    from amgx_tpu.amg.hierarchy import _first_member_order, _permute_host
+    from amgx_tpu.config import AMGConfig
+    A = poisson7(64)
+    agg = create_selector("SIZE_2", AMGConfig(), "default").select(A)
+    order = _first_member_order(agg, int(agg.max()) + 1)
+    Ad = host_pack(monkeypatch,
+                   _permute_host(galerkin_coarse(A, agg), order, 1))
+    assert Ad.n_rows == 162468
+    assert pack_kind(Ad) == "ell/window"
+    compile_for_chip(monkeypatch, one_chip, pallas_ell.ell_window_spmv, Ad)
+
+
 def test_binned_block_kernel_compiles_b4(monkeypatch, one_chip):
     # chip_smoke's block phase: a 4×4-block system on a 32³ grid
     rng = np.random.default_rng(0)
