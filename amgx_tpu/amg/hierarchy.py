@@ -1864,9 +1864,9 @@ class AMGHierarchy:
         reg = telemetry.registry()
         reg.gauge_clear("amgx_dist_overlap_fraction")
         reg.gauge_clear("amgx_dist_submesh_parts")
-        for i, Ad in enumerate(self._materialized_packs()):
-            if Ad is None or getattr(Ad, "fmt", "") != "sharded-ell":
-                continue
+        packs = self._materialized_packs()
+        for i, lay in self.dist_levels().items():
+            Ad = packs[i]
             try:
                 d = costmodel.dist_overlap(
                     Ad, nnz=sizes[i][1] if i < len(sizes) else None,
@@ -1878,22 +1878,47 @@ class AMGHierarchy:
             # the level's layout IS its sub-mesh: active_parts derives
             # from the (possibly agglomerated) offsets the level's
             # packs were built against
-            d["submesh_parts"] = d["active_parts"]
+            d["submesh_parts"] = lay["submesh_parts"]
+            d["interior"] = lay["interior"]
             telemetry.gauge_set("amgx_dist_overlap_fraction",
                                 d["overlap_fraction"], level=i)
             telemetry.gauge_set("amgx_dist_submesh_parts",
                                 d["submesh_parts"], level=i)
             telemetry.event("dist_overlap", **d)
 
+    def dist_levels(self) -> dict:
+        """Level index -> ``{"interior", "submesh_parts", "n_parts"}`` for
+        each level whose device pack is sharded and already exists: the
+        interior SpMV path its applies take (``window`` / ``gather`` /
+        ``block``, ``distributed.matrix.interior_path``) and the ranks
+        that own its rows.  Empty on single-device hierarchies."""
+        from ..distributed.agglomerate import active_parts
+        from ..distributed.matrix import interior_path
+        out = {}
+        for i, Ad in enumerate(self._materialized_packs()):
+            if getattr(Ad, "fmt", "") != "sharded-ell":
+                continue
+            out[i] = {"interior": interior_path(Ad),
+                      "submesh_parts": active_parts(Ad.offsets),
+                      "n_parts": int(Ad.n_parts)}
+        return out
+
     def grid_stats(self) -> str:
-        """Grid-stats table mirroring the reference README sample output."""
+        """Grid-stats table mirroring the reference README sample output;
+        a sharded level adds its interior SpMV path and the ranks that
+        own its rows (``sharded/window 4/4``)."""
         rows = []
         tot_rows = tot_nnz = 0
         all_levels = self.level_sizes()
+        dist = self.dist_levels()
         for i, (n, nnz) in enumerate(all_levels):
             sprs = nnz / max(n * n, 1)
+            d = dist.get(i)
+            tail = "" if d is None else (
+                f"  sharded/{d['interior']} "
+                f"{d['submesh_parts']}/{d['n_parts']}")
             rows.append(f"         {i}(D)  {n:12d}  {nnz:12d} "
-                        f" {sprs:9.3g}\n")
+                        f" {sprs:9.3g}{tail}\n")
             tot_rows += n
             tot_nnz += nnz
         op_cmpl = tot_nnz / max(all_levels[0][1], 1)

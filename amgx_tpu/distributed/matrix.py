@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import threading
 import weakref
 from typing import Optional
 
@@ -35,6 +36,7 @@ import scipy.sparse as sp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..errors import BadParametersError
+from ..telemetry import scopes as _tscopes
 from ..utils.jaxcompat import shard_map as _shard_map
 from .partition import Partition, build_partition
 
@@ -467,6 +469,57 @@ def uses_all_gather(dists: tuple, n_parts: int) -> bool:
     return n_parts > 1 and len(dists) >= n_parts - 1
 
 
+_halo_lock = threading.Lock()
+#: (op, ring) -> [exchanges, hops, wire bytes], counted as traced
+_halo_counts: dict = {}
+
+
+def _hops(A: "ShardedMatrix", ring: int):
+    """(path, collectives) of one ring-``ring`` exchange: the ACTUAL
+    collective count XLA executes — the all_gather fallback collapses
+    the whole distance schedule into ONE collective (its wire BYTES still
+    count every (P-1)-buffer the gather moves)."""
+    dists = A.dists if ring == 1 else A.dists2
+    if uses_all_gather(dists, A.n_parts):
+        return "all_gather", 1
+    return "ppermute", len(dists)
+
+
+def halo_stats() -> dict:
+    """Process totals of the halo exchanges traced so far, counted
+    whether telemetry is on or not: ``{"exchanges": n, "hops": n,
+    "wire_bytes": n, "by_op": {"<op>/ring<r>": {...}}}``.
+
+    An exchange counts when the Python body that emits it runs: once per
+    trace under ``jax.jit`` (the compiled program is unchanged and pays
+    nothing), once per call when eager.  ``hops`` are the collectives it
+    puts in the program (one ``ppermute`` per ring distance, or one
+    ``all_gather`` on the dense fallback); ``wire_bytes`` the padded
+    send buffers all shards put on the ICI, as
+    ``telemetry.costmodel.halo_wire_bytes`` models one exchange."""
+    with _halo_lock:
+        by = {f"{op}/ring{ring}": {"exchanges": c[0], "hops": c[1],
+                                   "wire_bytes": c[2]}
+              for (op, ring), c in sorted(_halo_counts.items())}
+    return {k: sum(d[k] for d in by.values())
+            for k in ("exchanges", "hops", "wire_bytes")} | {"by_op": by}
+
+
+def _count_halo(A: "ShardedMatrix", ring: int, op: str):
+    """Add one traced exchange to :func:`halo_stats` (none on one
+    part, whose exchange is the identity)."""
+    if A.n_parts == 1:
+        return
+    from ..telemetry import costmodel as _tcost
+    wire = int(_tcost.halo_wire_bytes(A, ring))
+    hops = _hops(A, ring)[1]
+    with _halo_lock:
+        c = _halo_counts.setdefault((op, ring), [0, 0, 0])
+        c[0] += 1
+        c[1] += hops
+        c[2] += wire
+
+
 def _tel_exchange(A: "ShardedMatrix", ring: int, op: str):
     """Halo-exchange telemetry (one attribute check when off).
 
@@ -483,17 +536,10 @@ def _tel_exchange(A: "ShardedMatrix", ring: int, op: str):
         return
     from ..telemetry import costmodel as _tcost
     from ..telemetry import metrics as _tmetrics
-    dists = A.dists if ring == 1 else A.dists2
-    path = "all_gather" if uses_all_gather(dists, A.n_parts) \
-        else "ppermute"
+    path, n_coll = _hops(A, ring)
     wire = _tcost.halo_wire_bytes(A, ring)
     entries = _tcost.halo_entries(A, ring)
     send_idx = A.send_idx if ring == 1 else A.send_idx2
-    # the ACTUAL collective count XLA executes: the all_gather fallback
-    # collapses the whole distance schedule into ONE collective —
-    # reporting len(dists) there overstated what is on the wire program
-    # (the wire BYTES still count every (P-1)-buffer the gather moves)
-    n_coll = 1 if path == "all_gather" else len(dists)
     _tmetrics.counter_inc("amgx_halo_exchange_total", ring=ring, op=op,
                           path=path)
     _tmetrics.counter_inc("amgx_halo_bytes_total", wire, ring=ring,
@@ -568,6 +614,7 @@ def exchange_halo(A: ShardedMatrix, x: jax.Array, ring: int = 1
     if ring not in (1, 2):
         raise BadParametersError(f"halo ring must be 1 or 2, got {ring}")
     from ..telemetry import recorder as _trecorder
+    _count_halo(A, ring, "exchange_halo")
     _tel_exchange(A, ring, "exchange_halo")
     # span over the host-level call: real wall time when eager, the
     # dispatch/trace cost under jit (the executed collective shows up in
@@ -585,7 +632,6 @@ def exchange_halo(A: ShardedMatrix, x: jax.Array, ring: int = 1
             got = _exchange(buf, dists, axis, A.n_parts)
             return got[hs[0]][None]
 
-        from ..telemetry import scopes as _tscopes
         with _tscopes.scope("dist", "halo_exchange"):
             return _shard_map(
                 local, mesh=A.mesh,
@@ -627,6 +673,7 @@ def dist_spmv(A: ShardedMatrix, x: jax.Array) -> jax.Array:
         return _dist_spmv_block(A, x)
     use_win = interior_path(A) == "window"
     from ..telemetry import recorder as _trecorder
+    _count_halo(A, 1, "dist_spmv")
     _tel_dist_spmv(A)
     sid = _trecorder.span_begin(
         "dist_spmv", {"n_parts": n_parts, "n_loc": A.n_loc,
@@ -658,21 +705,24 @@ def dist_spmv(A: ShardedMatrix, x: jax.Array) -> jax.Array:
         n_loc_r = cols.shape[0]       # output (row) shard size
         n_loc_c = xl.shape[0]         # input (column) shard size
         H = halo_src.shape[0]
-        buf = xl[send_idx]                                  # B2L gather
-        got = _exchange(buf, A.dists, axis, n_parts)
-        hvals = got[halo_src]                               # (H,)
+        with _tscopes.scope("dist", "halo_exchange"):
+            buf = xl[send_idx]                              # B2L gather
+            got = _exchange(buf, A.dists, axis, n_parts)
+            hvals = got[halo_src]                           # (H,)
         # interior: halo slots read zero — independent of the exchange
         xfull0 = jnp.concatenate([xl, jnp.zeros((H,), xl.dtype)])
         y0 = interior(cols, vals, xfull0, wb[0], wc[0], wv[0])
         # boundary rows get a small gathered correction scattered back
         # through a trash slot
-        rows = jnp.minimum(bnd, n_loc_r - 1)
-        cb = cols[rows]                                     # (Bd, K)
-        vb = vals[rows]
-        hb = jnp.where(cb >= n_loc_c,
-                       vb * hvals[jnp.clip(cb - n_loc_c, 0, H - 1)], 0.0)
-        corr = jnp.sum(hb, axis=1)                          # (Bd,)
-        yext = jnp.zeros((n_loc_r + 1,), xl.dtype).at[bnd].add(corr)
+        with _tscopes.scope("dist", "boundary"):
+            rows = jnp.minimum(bnd, n_loc_r - 1)
+            cb = cols[rows]                                 # (Bd, K)
+            vb = vals[rows]
+            hb = jnp.where(cb >= n_loc_c,
+                           vb * hvals[jnp.clip(cb - n_loc_c, 0, H - 1)],
+                           0.0)
+            corr = jnp.sum(hb, axis=1)                      # (Bd,)
+            yext = jnp.zeros((n_loc_r + 1,), xl.dtype).at[bnd].add(corr)
         return y0 + yext[:n_loc_r]
 
     # the win arrays always ride the shard_map signature (dummy scalars
@@ -705,6 +755,7 @@ def _dist_spmv_block(A: ShardedMatrix, x: jax.Array) -> jax.Array:
     einsums (the b×b MXU path)."""
     axis, n_parts, b = A.axis, A.n_parts, A.block_dim
     from ..telemetry import recorder as _trecorder
+    _count_halo(A, 1, "dist_spmv")
     _tel_dist_spmv(A)
     sid = _trecorder.span_begin(
         "dist_spmv", {"n_parts": n_parts, "n_loc": A.n_loc,
@@ -716,21 +767,23 @@ def _dist_spmv_block(A: ShardedMatrix, x: jax.Array) -> jax.Array:
         n_loc = cols.shape[0]
         H = halo_src.shape[0]
         xb = xl.reshape(n_loc, b)
-        buf = xb[send_idx]                                  # (B, b)
-        got = _exchange(buf, A.dists, axis, n_parts)        # (D·B, b)
-        hvals = got[halo_src]                               # (H, b)
+        with _tscopes.scope("dist", "halo_exchange"):
+            buf = xb[send_idx]                              # (B, b)
+            got = _exchange(buf, A.dists, axis, n_parts)    # (D·B, b)
+            hvals = got[halo_src]                           # (H, b)
         xfull0 = jnp.concatenate([xb, jnp.zeros((H, b), xl.dtype)])
         xg = xfull0[cols]                                   # (n,K,b)
         y0 = jnp.einsum("nkab,nkb->na", vals, xg,
                         preferred_element_type=vals.dtype)
-        rows = jnp.minimum(bnd, n_loc - 1)
-        cb = cols[rows]                                     # (Bd, K)
-        vb = vals[rows]                                     # (Bd,K,b,b)
-        hg = hvals[jnp.clip(cb - n_loc, 0, H - 1)]          # (Bd,K,b)
-        hb = jnp.einsum("nkab,nkb->na", vb,
-                        jnp.where((cb >= n_loc)[..., None], hg, 0.0),
-                        preferred_element_type=vals.dtype)
-        yext = jnp.zeros((n_loc + 1, b), xl.dtype).at[bnd].add(hb)
+        with _tscopes.scope("dist", "boundary"):
+            rows = jnp.minimum(bnd, n_loc - 1)
+            cb = cols[rows]                                 # (Bd, K)
+            vb = vals[rows]                                 # (Bd,K,b,b)
+            hg = hvals[jnp.clip(cb - n_loc, 0, H - 1)]      # (Bd,K,b)
+            hb = jnp.einsum("nkab,nkb->na", vb,
+                            jnp.where((cb >= n_loc)[..., None], hg, 0.0),
+                            preferred_element_type=vals.dtype)
+            yext = jnp.zeros((n_loc + 1, b), xl.dtype).at[bnd].add(hb)
         return (y0 + yext[:n_loc]).reshape(-1)
 
     try:

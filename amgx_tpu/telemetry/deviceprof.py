@@ -102,6 +102,29 @@ def _innermost_cycle(found: List[str]) -> Optional[str]:
     return None
 
 
+def _by_level(slices: List[_Slice], area: str) -> Dict[str, Dict[str, float]]:
+    """Device seconds of each name under ``amgx/<area>/``, by the cycle
+    level its operation ran in: ``"<i>"`` under ``amgx/cycle/level<i>``,
+    ``"coarse"`` under the coarse solve, ``"outside"`` outside the cycle
+    (the Krylov method's own operator applies).  Summed durations of
+    operations that hold no others, over the devices."""
+    pre = _AREA_PREFIX[area]
+    out: Dict[str, Dict[str, float]] = {}
+    for sl in slices:
+        names = [s[len(pre):] for s in sl.scopes if s.startswith(pre)]
+        if not names:
+            continue
+        inner = _innermost_cycle(sl.scopes)
+        m = _LEVEL_RE.match(inner or "")
+        lvl = m.group(1) if m else ("coarse" if inner else "outside")
+        d = out.setdefault(lvl, {})
+        d[names[-1]] = d.get(names[-1], 0.0) + (sl.end - sl.start)
+    return {k: {n: _round_s(us) for n, us in sorted(out[k].items())}
+            for k in sorted(out, key=lambda k: (not k.isdigit(),
+                                                int(k) if k.isdigit()
+                                                else 0, k))}
+
+
 def _pack_executions(slices: List[_Slice]) -> Dict[str, int]:
     """Executions of each SpMV pack counted in the trace.  A call site
     is the scope path up to its ``amgx/spmv/<pack>`` segment; it ran as
@@ -292,6 +315,11 @@ def measure_anatomy(trace: "str | dict | Iterable[dict]",
         "smoothers": _area("smoother"),
         "krylov": _area("krylov"),
         "dist": _area("dist"),
+        # the path each level's operator took (a sharded level's
+        # interior: sharded/window, sharded/gather or sharded/block) and
+        # where its halo exchanges ran
+        "spmv_by_level": _by_level(slices, "spmv"),
+        "dist_by_level": _by_level(slices, "dist"),
         "unscoped_top": _ranked(unscoped),
         "idle_by_span": _ranked(_idle_by_span(slices, spans)),
         "hbm_peak_gbs": float(peak_gbs) if peak_gbs else None,

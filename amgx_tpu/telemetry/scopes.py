@@ -27,7 +27,10 @@ The vocabulary per area:
 * ``krylov`` — the fixed stage vocabulary :data:`KRYLOV_STAGES`
   (solvers/krylov.py; ``refine``, a solve's true residuals and the f64
   refinement's corrections, in solvers/base.py)
-* ``dist``   — ``halo_exchange`` (distributed/matrix.py)
+* ``dist``   — :data:`DIST_STAGES`: ``halo_exchange`` (the B2L gather,
+  the collectives and the halo read of a sharded SpMV or a bare
+  ``exchange_halo``) and ``boundary`` (the SpMV's correction of its
+  boundary rows from the halo) (distributed/matrix.py)
 
 Bump :data:`SCOPE_VERSION` when names change meaning — the
 ``device_anatomy`` event carries it so old traces stay interpretable.
@@ -60,6 +63,9 @@ SPMV_PACKS = (
 #: the Krylov per-stage vocabulary (solvers/krylov.py)
 KRYLOV_STAGES = ("precond", "reduce", "arnoldi", "givens", "update",
                  "refine")
+
+#: the sharded SpMV's stages outside its interior (distributed/matrix.py)
+DIST_STAGES = ("halo_exchange", "boundary")
 
 #: the per-level cycle components (amg/cycles.py)
 CYCLE_COMPONENTS = ("pre_smooth", "post_smooth", "restrict", "prolong")
@@ -151,7 +157,7 @@ def canonicalize(raw: str) -> Optional[str]:
         if rest[0] in KRYLOV_STAGES:
             leaf = rest[:1]
     elif area == "dist":
-        if rest[0] == "halo_exchange":
+        if rest[0] in DIST_STAGES:
             leaf = rest[:1]
     if leaf is None:
         return None

@@ -99,6 +99,27 @@ def test_anatomy_ground_truth(chrome_trace):
         assert scopes.validate(s), s
 
 
+def test_spmv_and_dist_by_level(chrome_trace):
+    """Each SpMV pack and distributed stage is charged to the cycle
+    level it ran in: a sharded level's interior path (``sharded_gather``
+    here) and its boundary correction, nested under the level, the
+    smoother and the pack; the Krylov method's own halo exchange falls
+    outside the cycle."""
+    dd = ("amgx/cycle/level1/post_smooth/amgx/smoother/multicolor_dilu/"
+          "amgx/spmv/sharded/gather/amgx/dist/boundary/fusion.11")
+    events = chrome_trace["traceEvents"] + [
+        {"ph": "X", "pid": 0, "tid": 1, "ts": 400, "dur": 15,
+         "name": "fusion.11", "args": {"name": dd}}]
+    a = deviceprof.measure_anatomy(events)
+    assert a["spmv_by_level"] == {
+        "0": {"dia/slices": pytest.approx(100e-6)},
+        "1": {"sharded/gather": pytest.approx(15e-6)}}
+    assert a["dist_by_level"] == {
+        "1": {"boundary": pytest.approx(15e-6)},
+        "outside": {"halo_exchange": pytest.approx(20e-6)}}
+    assert a["dist"]["boundary"] == pytest.approx(15e-6)
+
+
 def test_per_level_sum_within_ten_percent_of_total(chrome_trace):
     """The acceptance criterion: levels + coarse ≈ total device time
     (levels 0 and 1 deliberately overlap in the fixture, so the sum
