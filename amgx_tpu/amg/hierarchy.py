@@ -1465,6 +1465,18 @@ class AMGHierarchy:
         nc = base
         if nc == 0 or nc >= n:
             return None, None, None
+        # number the coarse ids by each aggregate's first fine row, as
+        # the single-device levels are (``_relabel_aggregation_levels``):
+        # rank p's fine rows and coarse ids are both contiguous ranges,
+        # so the one global order only reorders within a rank and keeps
+        # every count.  Each product below (Galerkin, packs, re-bucketing,
+        # ``agg_pad``, the structure record) comes out renumbered, and
+        # the next level's selector sees the renumbered operator
+        order = _first_member_order(agg_real, nc)
+        if order is not None:
+            new_id = np.empty(nc, dtype=agg_real.dtype)
+            new_id[order] = np.arange(nc, dtype=agg_real.dtype)
+            agg_real = new_id[agg_real]
         coarse_offsets = np.concatenate([[0], np.cumsum(counts)])
 
         # per-rank Galerkin: rank p's coarse rows from rank p's row block;

@@ -176,3 +176,73 @@ def test_lowered_dd4_solve_scopes_its_halo_exchange(mesh4):
     assert perms
     assert all("amgx/dist/halo_exchange" in name for _, name in perms)
     assert "amgx/dist/boundary" in hlo
+
+
+def _rank_rows(h, m):
+    """(row offsets, per-rank row blocks with global columns) of one
+    distributed level operator."""
+    offsets = np.asarray(m.device().offsets)
+    return offsets, h._rank_blocks(m, offsets)
+
+
+@pytest.mark.parametrize("reuse", [0, 100])
+@pytest.mark.parametrize("n", [16, 17])
+def test_dd4_coarse_ids_in_first_member_order(mesh4, n, reuse):
+    """Each rank's coarse aggregates are numbered by their first fine
+    row inside the rank's own coarse range: the selector's partition and
+    count, and coarse blocks that are the per-rank Galerkin product of
+    the renumbered aggregates; the solve meets the tolerance before and
+    after a re-setup."""
+    from amgx_tpu.amg.aggregation.galerkin import galerkin_coarse
+    from amgx_tpu.amg.aggregation.selectors import create_selector
+    A = kron_poisson(n)
+    b = np.random.default_rng(n).standard_normal(n ** 3)
+    m = amgx.Matrix(A)
+    m.device_dtype = np.dtype(CONF["dtype"])
+    m.set_distribution(mesh4)
+    cfg = amgx.AMGConfig(CONF["solver_config"]
+                         + f", amg:structure_reuse_levels={reuse}")
+    slv = amgx.create_solver(cfg)
+    slv.setup(m)
+
+    def solve():
+        res = slv.solve(b, zero_initial_guess=True)
+        assert res.status == SolveStatus.SUCCESS
+        assert relres(A, np.asarray(res.x), b) <= TOL
+
+    solve()
+    h = slv.preconditioner.hierarchy
+    sel = create_selector("SIZE_2", cfg, "amg")
+    dist = [i for i, s in enumerate(h._structure)
+            if s[0] == "aggregation-dist"]
+    assert dist == list(range(len(h.levels)))
+    aggs = []
+    for i in dist:
+        agg, nc = h._structure[i][1]
+        aggs.append(agg.copy())
+        offsets, blocks = _rank_rows(h, h.levels[i].A)
+        base = 0
+        for p in range(4):
+            lo, hi = offsets[p], offsets[p + 1]
+            raw = sel.select(sp.csr_matrix(blocks[p][:, lo:hi]))
+            cnt = int(raw.max()) + 1
+            ids = agg[lo:hi] - base
+            # the rank's own range, the selector's count and partition
+            assert ids.min() == 0 and ids.max() == cnt - 1, (i, p)
+            assert len(np.unique(np.stack([raw, ids]), axis=1).T) == cnt
+            base += cnt
+        assert base == nc
+        # coarse ids in the order of each aggregate's first fine row
+        ids, first = np.unique(agg, return_index=True)
+        np.testing.assert_array_equal(ids, np.arange(nc))
+        assert (np.diff(first) > 0).all(), i
+        nxt = h.levels[i + 1].A if i + 1 < len(h.levels) else h.coarsest
+        want = galerkin_coarse(sp.csr_matrix(sp.vstack(blocks)), agg)
+        got = sp.csr_matrix(sp.vstack(_rank_rows(h, nxt)[1]))
+        assert abs(got - want).max() <= 1e-12 * abs(want).max(), i
+
+    slv.resetup(m)
+    h = slv.preconditioner.hierarchy
+    for i, agg in zip(dist, aggs):
+        np.testing.assert_array_equal(h._structure[i][1][0], agg)
+    solve()

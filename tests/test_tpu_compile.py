@@ -146,6 +146,38 @@ def test_window_kernel_compiles_aggregation_level(monkeypatch, one_chip):
     compile_for_chip(monkeypatch, one_chip, pallas_ell.ell_window_spmv, Ad)
 
 
+def test_window_kernel_compiles_dd4_rank_level(monkeypatch, one_chip):
+    # one rank of the 4-chip cell: the SIZE_2 level 1 of its 100×100×25
+    # slab of the 7-point Laplacian (the rank's diagonal block), coarse
+    # unknowns numbered by first fine row as the distributed coarsening
+    # numbers them — every row tile within the window kernel's budget
+    from amgx_tpu.amg.aggregation.galerkin import galerkin_coarse
+    from amgx_tpu.amg.aggregation.selectors import create_selector
+    from amgx_tpu.amg.hierarchy import _first_member_order, _permute_host
+    from amgx_tpu.config import AMGConfig
+    from amgx_tpu.ops.pallas_ell import _MAX_BLOCKS, ell_window_pack
+    line = [sp.diags([-1.0, 2.0, -1.0], [-1, 0, 1], shape=(m, m))
+            for m in (25, 100, 100)]
+    eye = [sp.identity(m) for m in (25, 100, 100)]
+    A = (sp.kron(sp.kron(line[0], eye[1]), eye[2])
+         + sp.kron(sp.kron(eye[0], line[1]), eye[2])
+         + sp.kron(sp.kron(eye[0], eye[1]), line[2])).tocsr()
+    agg = create_selector("SIZE_2", AMGConfig(), "default").select(A)
+    order = _first_member_order(agg, int(agg.max()) + 1)
+    assert order is not None
+    Ac = _permute_host(galerkin_coarse(A, agg), order, 1)
+    Ac.sort_indices()
+    deg = np.diff(Ac.indptr)
+    cols = np.zeros((Ac.shape[0], int(deg.max())), dtype=np.int64)
+    cols[np.repeat(np.arange(Ac.shape[0]), deg),
+         np.arange(Ac.nnz) - np.repeat(Ac.indptr[:-1], deg)] = Ac.indices
+    pack = ell_window_pack(cols)
+    assert pack is not None and pack[0].shape[1] <= _MAX_BLOCKS
+    Ad = host_pack(monkeypatch, Ac)
+    assert pack_kind(Ad) == "ell/window"
+    compile_for_chip(monkeypatch, one_chip, pallas_ell.ell_window_spmv, Ad)
+
+
 def test_binned_block_kernel_compiles_b4(monkeypatch, one_chip):
     # chip_smoke's block phase: a 4×4-block system on a 32³ grid
     rng = np.random.default_rng(0)
@@ -206,3 +238,25 @@ def test_sharded_spmv_compiles_on_2x2(monkeypatch, topo, path):
         compilation_cache.reset_cache()
     assert ("tpu_custom_call" in text) != wide
     assert ("f64" in text) == wide
+
+
+def test_dd4_sharded_levels_take_the_window_interior(monkeypatch):
+    """The 4-chip cell's stack at 16³ on 4 virtual devices, the window
+    packs built as on a chip (the interpreter switch): every sharded
+    scalar level's interior SpMV is the windowed kernel."""
+    import json
+    from pathlib import Path
+
+    import amgx_tpu as amgx
+    from amgx_tpu.distributed.matrix import make_mesh
+    conf = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                       / "configs" / "poisson7-agg-dd4.json").read_text())
+    monkeypatch.setattr(pallas_ell, "_INTERPRET", True)
+    m = amgx.Matrix(poisson7(16))
+    m.device_dtype = np.dtype(conf["dtype"])
+    m.set_distribution(make_mesh(4))
+    slv = amgx.create_solver(amgx.AMGConfig(conf["solver_config"]))
+    slv.setup(m)
+    lay = slv.preconditioner.hierarchy.dist_levels()
+    assert len(lay) >= 5
+    assert all(d["interior"] == "window" for d in lay.values()), lay
