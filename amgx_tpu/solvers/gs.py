@@ -22,64 +22,9 @@ from ..coloring import color_matrix
 from ..ops.spmv import spmv
 from ..utils.jaxcompat import shard_map as _shard_map
 from .base import Solver, register_solver
+from .colorsweep import (ColorSlab, build_color_slabs, stack_color_slabs,
+                         sweep)
 from .jacobi import _apply_dinv, setup_dinv
-
-
-class ColorSlab:
-    """One color's compact row slab: ELL rows with GLOBAL column ids."""
-
-    def __init__(self, rows, cols, vals):
-        self.rows = rows        # (nc,) int32 — this color's (block) rows
-        self.cols = cols        # (nc, K) int32
-        self.vals = vals        # (nc, K[, b, b])
-
-
-def build_color_slabs(csr, colors, num_colors, dtype, device=True):
-    """Per-color packed ELL slabs from a scalar CSR matrix
-    (multicolor_dilu_solver.cu per-color kernel data, TPU-packed);
-    ``device=False`` keeps host arrays (the distributed packer stacks
-    and re-shards them itself)."""
-    from ..core.matrix import ell_layout
-    wrap = jnp.asarray if device else (lambda x: x)
-    slabs = []
-    for c in range(num_colors):
-        rows = np.where(colors == c)[0]
-        sub = csr[rows]
-        sub.sort_indices()
-        for_rows, pos, k = ell_layout(sub.indptr, sub.indices)
-        cols = np.zeros((len(rows), k), dtype=np.int32)
-        vals = np.zeros((len(rows), k), dtype=dtype)
-        cols[for_rows, pos] = sub.indices
-        vals[for_rows, pos] = sub.data
-        slabs.append(ColorSlab(wrap(rows.astype(np.int32)),
-                               wrap(cols), wrap(vals)))
-    return slabs
-
-
-def build_color_slabs_block(bsr, colors, num_colors, dtype, bd,
-                            device=True):
-    """Per-color packed block-ELL slabs from a BSR matrix: cols are BLOCK
-    columns, vals (nc, K, b, b); ``device=False`` keeps host arrays (the
-    distributed packer stacks and re-shards them itself)."""
-    import scipy.sparse as sp
-    from ..core.matrix import ell_layout
-    wrap = jnp.asarray if device else (lambda x: x)
-    bsr.sort_indices()
-    ind = sp.csr_matrix(
-        (np.arange(len(bsr.indices)), bsr.indices, bsr.indptr),
-        shape=(bsr.shape[0] // bd, bsr.shape[1] // bd))
-    slabs = []
-    for c in range(num_colors):
-        rows = np.where(colors == c)[0]
-        sub = ind[rows]
-        for_rows, pos, k = ell_layout(sub.indptr, sub.indices)
-        cols = np.zeros((len(rows), k), dtype=np.int32)
-        vals = np.zeros((len(rows), k, bd, bd), dtype=dtype)
-        cols[for_rows, pos] = sub.indices
-        vals[for_rows, pos] = bsr.data[sub.data]
-        slabs.append(ColorSlab(wrap(rows.astype(np.int32)),
-                               wrap(cols), wrap(vals)))
-    return slabs
 
 
 class _ColoredSmootherBase(Solver):
@@ -102,17 +47,8 @@ class _ColoredSmootherBase(Solver):
         self.dist_slab_rows = None
         if build_slabs and self.Ad.fmt != "sharded-ell" \
                 and self.A is not None:
-            if b == 1:
-                self.color_slabs = build_color_slabs(
-                    self.A.scalar_csr(), colors, self.num_colors,
-                    self.Ad.dtype)
-            else:
-                import scipy.sparse as sp
-                bsr = self.A.host if isinstance(self.A.host,
-                                                sp.bsr_matrix) else \
-                    sp.bsr_matrix(self.A.host, blocksize=(b, b))
-                self.color_slabs = build_color_slabs_block(
-                    bsr, colors, self.num_colors, self.Ad.dtype, b)
+            self.color_slabs = build_color_slabs(
+                self.A.host, colors, self.num_colors, self.Ad.dtype, b)
         elif build_slabs and dist_slabs \
                 and self.Ad.fmt == "sharded-ell" and b == 1 \
                 and self.A is not None:
@@ -140,26 +76,17 @@ class _ColoredSmootherBase(Solver):
         self.dinv = setup_dinv(self)
 
     def _stack_dist_color_rows(self, colors):
-        """(P, Rc) local row ids per color, padded with the trash id
-        ``n_loc`` (the sweep clamps for gathering and scatters pads into
-        a trash slot)."""
-        import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
+        """Per color, each rank's local row ids as row-only slabs, padded
+        with the trash slot ``n_loc + H`` of the distributed sweep's
+        ``[local | frozen halo | trash]`` vector."""
         Ad = self.Ad
         offs = np.asarray(Ad.offsets)
-        n_parts, n_loc = Ad.n_parts, Ad.n_loc
-        out = []
-        for c in range(self.num_colors):
-            per_rank = [np.flatnonzero(colors[offs[p]:offs[p + 1]] == c)
-                        for p in range(n_parts)]
-            Rc = max(max(len(r) for r in per_rank), 1)
-            rows = np.full((n_parts, Rc), n_loc, dtype=np.int32)
-            for p, r in enumerate(per_rank):
-                rows[p, :len(r)] = r
-            out.append(jax.device_put(
-                rows, NamedSharding(Ad.mesh, P(Ad.axis, None))))
-        return out
+        per_rank = [[ColorSlab(np.flatnonzero(cp == c).astype(np.int32),
+                               None, None)
+                     for c in range(self.num_colors)]
+                    for cp in np.split(colors, offs[1:-1])]
+        trash = Ad.n_loc + Ad.halo_src.shape[1]
+        return stack_color_slabs(per_rank, trash, Ad.mesh, Ad.axis)
 
 
 def _shard_transpose(A, Ad):
@@ -265,6 +192,18 @@ class MulticolorGSSolver(_ColoredSmootherBase):
             else:
                 self.dinv = jnp.asarray(vec)
 
+    def _relax(self, b, dinv):
+        """The row update ω·D⁻¹(b − A·x) of the rows one color writes,
+        from their row dot ``t`` = (A·x)_i."""
+        relax = self.relaxation_factor
+
+        def update(i, t):
+            r_c = b[i] - t
+            if dinv.ndim > r_c.ndim:
+                return relax * jnp.einsum("nab,nb->na", dinv[i], r_c)
+            return relax * dinv[i] * r_c
+        return update
+
     def _color_sweep(self, b, x, order):
         if getattr(self, "dist_slab_rows", None) is not None:
             return self._dist_color_sweep(b, x, order)
@@ -276,30 +215,11 @@ class MulticolorGSSolver(_ColoredSmootherBase):
                 x = jnp.where(self.color_masks[c], x + dx, x)
             return x
         bd = self.Ad.block_dim
-        relax = self.relaxation_factor
-        if bd == 1:
-            for c in order:
-                s = self.color_slabs[c]
-                r_c = b[s.rows] - jnp.sum(s.vals * x[s.cols], axis=1)
-                x = x.at[s.rows].add(relax * self.dinv[s.rows] * r_c)
-            return x
-        for c in order:
-            s = self.color_slabs[c]
-            xg = x.reshape(-1, bd)[s.cols]                 # (nc, K, b)
-            # sub-f32 slab values (bf16 hierarchy) accumulate in f32 —
-            # the same floor every SpMV path applies (core/precision.py)
-            from ..core.precision import compute_dtype as _cdt
-            pet = jnp.promote_types(_cdt(s.vals.dtype), xg.dtype)
-            Ax = jnp.einsum("nkab,nkb->na", s.vals, xg,
-                            preferred_element_type=pet)
-            r_c = b.reshape(-1, bd)[s.rows] - Ax
-            if self.dinv.ndim == 1:    # L1 variant: scalar damped diag
-                dx = relax * self.dinv.reshape(-1, bd)[s.rows] * r_c
-            else:
-                dx = relax * jnp.einsum("nab,nb->na", self.dinv[s.rows],
-                                        r_c)
-            x = x.reshape(-1, bd).at[s.rows].add(dx).reshape(-1)
-        return x
+        rows = (lambda v: v) if bd == 1 else (lambda v: v.reshape(-1, bd))
+        # a block smoother's L1 variant keeps a scalar damped diagonal
+        dinv = rows(self.dinv) if self.dinv.ndim == 1 else self.dinv
+        return sweep(rows(x), self.color_slabs, order,
+                     self._relax(rows(b), dinv), add=True).reshape(-1)
 
     def _dist_color_sweep(self, b, x, order):
         """Distributed color-ordered sweep: ONE halo exchange at sweep
@@ -312,40 +232,27 @@ class MulticolorGSSolver(_ColoredSmootherBase):
         from ..distributed.matrix import _exchange
         A = self.Ad
         axis, n_parts, n_loc = A.axis, A.n_parts, A.n_loc
-        relax = self.relaxation_factor
         order = list(order)
 
-        def local(cols, vals, send_idx, halo_src, slab_rows, dinv, bl,
-                  xl):
-            cols, vals = cols[0], vals[0]
-            send_idx, halo_src = send_idx[0], halo_src[0]
-            H = halo_src.shape[0]
-            buf = xl[send_idx]
-            got = _exchange(buf, A.dists, axis, n_parts)
-            hvals = got[halo_src]
+        def local(cols, vals, send_idx, halo_src, slabs, dinv, bl, xl):
+            cols, vals, send_idx, halo_src, slabs = jax.tree_util.tree_map(
+                lambda a: a[0], (cols, vals, send_idx, halo_src, slabs))
+            got = _exchange(xl[send_idx], A.dists, axis, n_parts)
             # [local | frozen halo | trash]
-            xe = jnp.concatenate([xl, hvals,
+            xe = jnp.concatenate([xl, got[halo_src],
                                   jnp.zeros((1,), xl.dtype)])
-            for c in order:
-                rows = slab_rows[c][0]
-                rsafe = jnp.minimum(rows, n_loc - 1)
-                cc = cols[rsafe]                  # (Rc, K)
-                vv = vals[rsafe]
-                r_c = bl[rsafe] - jnp.sum(vv * xe[cc], axis=1)
-                upd = relax * dinv[rsafe] * r_c
-                wr = jnp.where(rows >= n_loc, n_loc + H, rows)
-                xe = xe.at[wr].add(upd)
+            xe = sweep(xe, slabs, order, self._relax(bl, dinv), add=True,
+                       n_loc=n_loc, pack=(cols, vals))
             return xe[:n_loc]
 
-        spec2 = P(axis, None)
+        spec = lambda a: P(axis, *[None] * (a.ndim - 1))
+        args = (A.cols, A.vals, A.send_idx, A.halo_src, self.dist_slab_rows)
         return _shard_map(
             local, mesh=A.mesh,
-            in_specs=(P(axis, None, None), P(axis, None, None),
-                      spec2, spec2, [spec2] * len(self.dist_slab_rows),
-                      P(axis), P(axis), P(axis)),
+            in_specs=(*jax.tree_util.tree_map(spec, args), P(axis), P(axis),
+                      P(axis)),
             out_specs=P(axis), check_vma=False,
-        )(A.cols, A.vals, A.send_idx, A.halo_src, self.dist_slab_rows,
-          self.dinv, b, x)
+        )(*args, self.dinv, b, x)
 
     def solve_iteration(self, b, x, state, iter_idx):
         x = self._color_sweep(b, x, range(self.num_colors))

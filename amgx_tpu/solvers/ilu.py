@@ -7,8 +7,9 @@ TPU design: the matrix is factorised on host in *color-rank order* (the
 reference reorders by color, ``reorderColumnsByColor``); the triangular
 solves then parallelise color-by-color.  To keep that true with fill-in,
 the coloring is computed on the *filled* sparsity graph L+U — rows of one
-color stay mutually independent for any sparsity level k, so each solve
-sweep is ``num_colors`` masked SpMV updates, as in DILU.
+color stay mutually independent for any sparsity level k, so each
+triangular solve is one color-ordered slab sweep
+(:func:`.colorsweep.sweep`), as in DILU.
 """
 from __future__ import annotations
 
@@ -16,11 +17,11 @@ import jax.numpy as jnp
 import numpy as np
 import scipy.sparse as sp
 
-from ..coloring import MatrixColoring, create_coloring
-from ..core.matrix import pack_device
+from ..coloring import create_coloring
 from ..errors import BadConfigurationError
 from ..ops.spmv import spmv
 from .base import Solver, register_solver
+from .colorsweep import build_color_slabs, split_lower_upper, sweep
 
 
 def _symbolic_fill(A: sp.csr_matrix, level: int) -> sp.csr_matrix:
@@ -128,24 +129,11 @@ class MulticolorILUSolver(Solver):
         coloring = algo.color(pattern)
         colors = coloring.colors
         self.num_colors = coloring.num_colors
-        rank = colors.astype(np.int64)
-        LU, dinv = _ilu_factorize(csr, pattern, rank)
-        n = csr.shape[0]
-        rows = np.repeat(np.arange(n), np.diff(LU.indptr))
-        lower = rank[LU.indices] < rank[rows]
-        upper = rank[LU.indices] > rank[rows]
-        L = sp.csr_matrix((np.where(lower, LU.data, 0.0),
-                           LU.indices.copy(), LU.indptr.copy()),
-                          shape=LU.shape)
-        L.eliminate_zeros()
-        U = sp.csr_matrix((np.where(upper, LU.data, 0.0),
-                           LU.indices.copy(), LU.indptr.copy()),
-                          shape=LU.shape)
-        U.eliminate_zeros()
+        LU, dinv = _ilu_factorize(csr, pattern, colors.astype(np.int64))
+        L, U = split_lower_upper(LU, colors)
         # per-color packed slabs: each triangular-solve sweep reads only
         # its color's L/U rows — O(nnz(LU)) per application, independent
         # of the color count (the reference's per-color kernels)
-        from .gs import build_color_slabs
         self.L_slabs = build_color_slabs(L, colors, self.num_colors,
                                          self.Ad.dtype)
         self.U_slabs = build_color_slabs(U, colors, self.num_colors,
@@ -153,19 +141,13 @@ class MulticolorILUSolver(Solver):
         self.dinv_f = jnp.asarray(dinv.astype(self.Ad.dtype))
 
     def _apply_ilu(self, r):
+        C = self.num_colors
         # L y = r  (unit lower): y_c = r_c − (L·y)_c
-        y = jnp.zeros_like(r)
-        for c in range(self.num_colors):
-            s = self.L_slabs[c]
-            t = jnp.sum(s.vals * y[s.cols], axis=1)
-            y = y.at[s.rows].set(r[s.rows] - t)
+        y = sweep(jnp.zeros_like(r), self.L_slabs, range(C),
+                  lambda i, t: r[i] - t)
         # U z = y: z_c = dinv_c (y − U·z)_c
-        z = jnp.zeros_like(r)
-        for c in range(self.num_colors - 1, -1, -1):
-            s = self.U_slabs[c]
-            t = jnp.sum(s.vals * z[s.cols], axis=1)
-            z = z.at[s.rows].set(self.dinv_f[s.rows] * (y[s.rows] - t))
-        return z
+        return sweep(jnp.zeros_like(r), self.U_slabs, range(C - 1, -1, -1),
+                     lambda i, t: self.dinv_f[i] * (y[i] - t))
 
     def solve_iteration(self, b, x, state, iter_idx):
         r = b - spmv(self.Ad, x)

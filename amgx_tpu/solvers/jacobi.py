@@ -81,6 +81,10 @@ def setup_dinv(slv) -> jax.Array:
     Ad, A = slv.Ad, slv.A
     if Ad.fmt == "sharded-ell":
         d = Ad.diag
+        if Ad.block_dim > 1:
+            # batched b×b inverses, on the pack's sharding (an entrywise
+            # reciprocal inverts only diagonal blocks)
+            return jax.device_put(_invert_block_diag(d), d.sharding)
         return jnp.where(d != 0, 1.0 / jnp.where(d == 0, 1.0, d), 0.0)
     if A is not None:
         cached = getattr(A, "_dinv_dev", None)

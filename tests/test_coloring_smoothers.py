@@ -408,3 +408,155 @@ def test_70_clique_proper_coloring():
         # a clique admits no repeated color at all
         assert col.num_colors == n, (scheme, col.num_colors)
         assert len(np.unique(col.colors)) == n, scheme
+
+
+# ------------------------------------------------ one apply vs an oracle
+@pytest.fixture(scope="module")
+def mesh():
+    from amgx_tpu.distributed.matrix import make_mesh
+    return make_mesh(4)
+
+
+def _oracle_matrix(b):
+    """A nonsymmetric 6³ 7-point operator (its L and U differ, so a
+    transposed coupling shows), scalar or with full b×b blocks."""
+    rng = np.random.default_rng(11)
+    A = sp.csr_matrix(poisson7pt(6, 6, 6))
+    if b > 1:
+        A = sp.csr_matrix(sp.kron(A, np.eye(b) + 0.2 * rng.random((b, b))))
+    A.data = A.data * (1 + 0.1 * rng.standard_normal(A.nnz))
+    return A
+
+
+def _dense_blocks(A, b):
+    """(N, N, b, b) block view of a dense copy of A."""
+    n = A.shape[0] // b
+    return A.toarray().reshape(n, b, n, b).transpose(0, 2, 1, 3)
+
+
+def _from_blocks(B):
+    n, _, b, _ = B.shape
+    return B.transpose(0, 2, 1, 3).reshape(n * b, n * b)
+
+
+def _dilu_M(Ab, col):
+    """(E + L)·E⁻¹·(E + U) with E by the DILU recurrence, in color order:
+    E_i = A_ii − Σ_{col_j < col_i} A_ij·E_j⁻¹·A_ji."""
+    n, b = Ab.shape[0], Ab.shape[2]
+    Einv = np.zeros((n, b, b))
+    Eb = np.zeros_like(Ab)
+    for i in np.argsort(col, kind="stable"):
+        low = col < col[i]
+        E = Ab[i, i] - np.einsum("jab,jbc,jcd->ad", Ab[i, low], Einv[low],
+                                 Ab[low, i])
+        Eb[i, i] = E
+        Einv[i] = np.linalg.inv(E)
+    lower = (col[None, :] < col[:, None])[:, :, None, None]
+    upper = (col[None, :] > col[:, None])[:, :, None, None]
+    E = _from_blocks(Eb)
+    L = _from_blocks(np.where(lower, Ab, 0.0))
+    U = _from_blocks(np.where(upper, Ab, 0.0))
+    return (E + L) @ np.linalg.inv(E) @ (E + U)
+
+
+def _ilu0_M(A, col):
+    """L·U of ILU(0) on A's pattern with rows eliminated in color order
+    (L unit lower, U upper with the diagonal)."""
+    perm = np.argsort(col, kind="stable")
+    B = A.toarray()[np.ix_(perm, perm)]
+    S = B != 0
+    n = len(B)
+    for i in range(n):
+        for k in np.flatnonzero(S[i, :i]):
+            B[i, k] /= B[k, k]
+            js = k + 1 + np.flatnonzero(S[i, k + 1:])
+            B[i, js] -= B[i, k] * B[k, js]
+    M = (np.tril(B, -1) + np.eye(n)) @ np.triu(B)
+    inv = np.argsort(perm)
+    return M[np.ix_(inv, inv)]
+
+
+def _gs_oracle(Ab, col, rhs, x, omega, offs=None):
+    """Symmetric color-ordered GS: each color's rows relax together,
+    x_i += ω·A_ii⁻¹(b − A·x)_i, forward then backward.  With rank
+    offsets, couplings between ranks read x as it stood at sweep start
+    (the halo is exchanged once a sweep, then frozen)."""
+    n, b = Ab.shape[0], Ab.shape[2]
+    own = np.zeros(n, int) if offs is None else \
+        np.repeat(np.arange(len(offs) - 1), np.diff(offs))
+    same = (own[:, None] == own[None, :])[:, :, None, None]
+    A_in = _from_blocks(np.where(same, Ab, 0.0))
+    A_out = _from_blocks(np.where(same, 0.0, Ab))
+    Dinv = np.linalg.inv(Ab[np.arange(n), np.arange(n)])
+    C = int(col.max()) + 1
+    x = x.copy()
+    for order in (range(C), range(C - 1, -1, -1)):
+        x0 = x.copy()
+        for c in order:
+            rows = np.flatnonzero(col == c)
+            r = (rhs - A_in @ x - A_out @ x0).reshape(n, b)[rows]
+            upd = omega * np.einsum("nab,nb->na", Dinv[rows], r)
+            x = x.reshape(n, b)
+            x[rows] += upd
+            x = x.reshape(-1)
+    return x
+
+
+@pytest.mark.parametrize("smoother,b,dist", [
+    ("MULTICOLOR_DILU", 1, False), ("MULTICOLOR_DILU", 3, False),
+    ("MULTICOLOR_ILU", 1, False),
+    ("MULTICOLOR_GS", 1, False), ("MULTICOLOR_GS", 3, False),
+    ("MULTICOLOR_DILU", 1, True), ("MULTICOLOR_DILU", 3, True),
+    ("MULTICOLOR_GS", 1, True), ("MULTICOLOR_GS", 3, True)])
+def test_one_smoother_step_matches_dense_oracle(request, smoother, b, dist):
+    """One f64 smoother step x + ω·M⁻¹(b − A·x) (GS: one symmetric
+    sweep) against dense numpy built from the smoother's own coloring.
+    On 4 virtual devices DILU's M is block-diagonal — each rank's M of
+    its local block — and the slab GS freezes the halo at sweep start;
+    the block GS there is the masked sweep, with a fresh halo a color."""
+    from amgx_tpu.distributed.matrix import shard_vector, unshard_vector
+    A = _oracle_matrix(b)
+    m = amgx.Matrix(A, block_dim=b)
+    m.device_dtype = np.float64
+    if dist:
+        m.set_distribution(request.getfixturevalue("mesh"))
+    cfg = amgx.AMGConfig(f"config_version=2, solver(s)={smoother}, "
+                         "s:max_iters=1, s:symmetric_GS=1")
+    slv = amgx.SolverFactory.create(smoother, cfg, "s")
+    slv.setup(m)
+    Ad = m.device()
+    rng = np.random.default_rng(5)
+    rhs, x = rng.standard_normal((2, A.shape[0]))
+    import jax.numpy as jnp
+    put = (lambda v: shard_vector(Ad, v)) if dist else jnp.asarray
+    got = np.asarray(slv.solve_iteration(put(rhs), put(x), (), 0)[0])
+    if dist:
+        got = unshard_vector(Ad, got)
+
+    Ab = _dense_blocks(A, b)
+    omega = slv.relaxation_factor
+    offs = np.asarray(Ad.offsets) if dist else None
+    if smoother == "MULTICOLOR_ILU":
+        col = np.zeros(A.shape[0], int)
+        for c, s in enumerate(slv.L_slabs):
+            col[np.asarray(s.rows)] = c
+    else:
+        col = np.asarray(slv.A.coloring.colors)
+    assert col.max() > 0
+    if smoother == "MULTICOLOR_GS":
+        slab_gs = offs is not None and b == 1
+        want = _gs_oracle(Ab, col, rhs, x, omega,
+                          offs if slab_gs else None)
+    else:
+        if smoother == "MULTICOLOR_ILU":
+            M = _ilu0_M(A, col)
+        elif dist:
+            import scipy.linalg
+            M = scipy.linalg.block_diag(*[
+                _dilu_M(Ab[lo:hi, lo:hi], col[lo:hi])
+                for lo, hi in zip(offs[:-1], offs[1:])])
+        else:
+            M = _dilu_M(Ab, col)
+        want = x + omega * np.linalg.solve(M, rhs - A @ x)
+    np.testing.assert_allclose(got, want, rtol=1e-12,
+                               atol=1e-12 * np.abs(want).max())

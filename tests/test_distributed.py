@@ -577,28 +577,72 @@ def _count_collectives(jaxpr) -> int:
     return n
 
 
-def test_dist_dilu_slab_sweeps_no_collectives(mesh):
-    """Distributed DILU sweeps are per-rank slab kernels with ZERO
-    collectives (halo values are frozen at sweep start, exchanged once
-    by the outer residual — multicolor_dilu_solver.cu:4167-4209); cost
-    is O(nnz_shard), not O(num_colors·nnz)."""
-    A = poisson7pt(8, 8, 8)
+def _sweep_ops(jaxpr):
+    """Counts of the sweep's gather/scatter equations and of shard_maps,
+    and the gathers and scatters that lie inside a shard_map."""
+    counts = {"gather": 0, "scatter": 0, "scatter-add": 0, "shard_map": 0}
+    inside = 0
+    stack = [(jaxpr.jaxpr, False)]
+    while stack:
+        jx, in_map = stack.pop()
+        for eqn in jx.eqns:
+            name = eqn.primitive.name
+            if name in counts:
+                counts[name] += 1
+                inside += in_map and name != "shard_map"
+            for sub in eqn.params.values():
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    stack.append((sub, in_map or name == "shard_map"))
+    return counts, inside
+
+
+def _dilu_apply_jaxpr(A, mesh=None):
     m = amgx.Matrix(A)
-    m.set_distribution(mesh)
+    if mesh is not None:
+        m.set_distribution(mesh)
     cfg = amgx.AMGConfig(
         "config_version=2, solver(out)=MULTICOLOR_DILU, out:max_iters=2, "
         "out:monitor_residual=1")
     slv = amgx.create_solver(cfg)
     slv.setup(m)
-    assert slv.num_colors > 1
-    assert getattr(slv, "_dist_L", None) is not None
-    r = shard_vector(m.device(), np.ones(A.shape[0]))
-    jaxpr = jax.make_jaxpr(slv._apply_dilu)(r)
+    r = np.ones(A.shape[0])
+    r = shard_vector(m.device(), r) if mesh is not None else jnp.asarray(r)
+    return slv, jax.make_jaxpr(slv._apply_dilu)(r)
+
+
+def test_dist_dilu_slab_sweeps_no_collectives(mesh):
+    """Distributed DILU sweeps are per-rank slab kernels with ZERO
+    collectives (halo values are frozen at sweep start, exchanged once
+    by the outer residual — multicolor_dilu_solver.cu:4167-4209); cost
+    is O(nnz_shard), not O(num_colors·nnz).  Per color, each of the two
+    sweeps gathers three times (the vector at the slab's columns, E⁻¹
+    and the right-hand side at its rows) and writes its rows with one
+    plain scatter, all inside one shard_map."""
+    A = poisson7pt(8, 8, 8)
+    slv, jaxpr = _dilu_apply_jaxpr(A, mesh)
+    C = slv.num_colors
+    assert C > 1
     assert _count_collectives(jaxpr) == 0, jaxpr
+    counts, inside = _sweep_ops(jaxpr)
+    assert counts == {"gather": 6 * C, "scatter": 2 * C, "scatter-add": 0,
+                      "shard_map": 1}, (counts, C)
+    assert inside == 8 * C, (inside, C)
     # slab storage is O(nnz_shard): total slab entries ≤ nnz + padding
-    tot = sum(int(np.prod(t[2].shape)) for t in slv._dist_L) + \
-        sum(int(np.prod(t[2].shape)) for t in slv._dist_U)
+    tot = sum(int(np.prod(s.vals.shape)) for s in slv.L_slabs) + \
+        sum(int(np.prod(s.vals.shape)) for s in slv.U_slabs)
     assert tot <= 2 * A.nnz, (tot, A.nnz)
+
+
+def test_dilu_slab_sweeps_op_counts_one_device():
+    """On one device the DILU apply is the same sweep with no shard_map:
+    6 gathers and 2 plain scatters per color, no scatter-add."""
+    slv, jaxpr = _dilu_apply_jaxpr(poisson7pt(8, 8, 8))
+    C = slv.num_colors
+    assert C > 1
+    counts, _ = _sweep_ops(jaxpr)
+    assert counts == {"gather": 6 * C, "scatter": 2 * C, "scatter-add": 0,
+                      "shard_map": 0}, (counts, C)
 
 
 def test_dist_gs_one_exchange_per_sweep(mesh):
